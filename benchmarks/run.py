@@ -92,6 +92,9 @@ def main() -> None:
                          "(Perfetto-loadable; bare filenames land under "
                          "out/)")
     args = ap.parse_args()
+    from repro import compile_cache
+
+    compile_cache.configure()
     args.json = _artifact_path(args.json)
     args.trace = _artifact_path(args.trace)
 

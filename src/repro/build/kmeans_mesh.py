@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.compat import make_mesh, shard_map
+from repro.core.kmeans import _assign_chunked
 from repro.distributed.reduce import ordered_block_sum
 
 #: mesh axis name the build collectives run over
@@ -50,15 +51,15 @@ def build_mesh(n_devices: int | None = None):
         raise ValueError(
             f"n_devices={n} exceeds the {len(devices)} visible devices"
         )
-    return jax.make_mesh((n,), (BUILD_AXIS,), devices=devices[:n])
+    return make_mesh((n,), (BUILD_AXIS,), devices=devices[:n])
 
 
 def _block_stats(xb: jax.Array, wb: jax.Array, cents: jax.Array):
-    """One block's per-cluster (sums, counts); padded rows carry weight 0."""
+    """One block's per-cluster (sums, counts); padded rows carry weight 0.
+    Assignment is ``core.kmeans``' chunked argmin, so no (block, K)
+    distance matrix materializes at large K."""
     k = cents.shape[0]
-    c_sq = jnp.sum(cents**2, axis=-1)
-    d2 = c_sq[None, :] - 2.0 * (xb @ cents.T)
-    codes = jnp.argmin(d2, axis=-1).astype(jnp.int32)
+    codes, _ = _assign_chunked(xb, cents)
     w = wb.astype(jnp.float32)
     sums = jax.ops.segment_sum(xb * w[:, None], codes, num_segments=k)
     counts = jax.ops.segment_sum(w, codes, num_segments=k)
@@ -72,8 +73,8 @@ def _fit_program(mesh, k: int, iters: int, stat_blocks: int):
     def local_stats(xb_local, wb_local, cents):
         # (local_blocks, block, d) -> per-block partials, then the two
         # deterministic combines described in the module docstring
-        sums_b, counts_b = jax.vmap(_block_stats, in_axes=(0, 0, None))(
-            xb_local, wb_local, cents
+        sums_b, counts_b = jax.lax.map(
+            lambda xw: _block_stats(*xw, cents), (xb_local, wb_local)
         )
         sums = ordered_block_sum(sums_b, BUILD_AXIS)
         counts = jax.lax.psum(jnp.sum(counts_b, axis=0), BUILD_AXIS)

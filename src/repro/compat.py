@@ -1,36 +1,34 @@
-"""jax version-compatibility shims shared across the framework.
+"""The repo's one home for jax API drift (installed floor: jax 0.9).
 
-One home for the API drift the repo has to straddle (pinned CI image runs
-jax 0.4.37; dev boxes may run >= 0.8):
-
-* ``shard_map`` — moved from ``jax.experimental.shard_map`` to the public
-  ``jax.shard_map`` and renamed its ``check_rep`` knob to ``check_vma``.
-  Import it from here (keyword-only, ``check_rep=``) instead of guessing
-  which spelling the installed jax speaks.
-* ``axis_size`` — ``jax.lax.axis_size`` only exists on newer jax; older
-  versions constant-fold ``psum(1, axis)`` to the same value.
+* ``shard_map`` — keyword-only, with the repo's ``check_rep=`` spelling of
+  jax's ``check_vma`` knob.
+* ``make_mesh`` — ``jax.make_mesh`` makes ``Explicit`` axes by default, which
+  turns every sharded operand's layout into part of its type (a
+  ``dynamic_update_slice`` inside a sharded jit then rejects mismatched
+  operand/update shardings).  The repo's meshes are ``Auto``: sharding is
+  placed by ``shard_map`` specs and ``NamedSharding`` puts, and XLA
+  propagates the rest.
 
 Everything here is import-time cheap and side-effect free.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.8: public API; check_vma replaces check_rep
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep,
-        )
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from jax.sharding import AxisType
 
 
-def axis_size(axis_name) -> int:
-    """Size of a mapped axis, inside ``shard_map``/``pmap`` tracing."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # jax < 0.5: psum of a literal constant-folds to the axis size
-    return jax.lax.psum(1, axis_name)
+def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_rep,
+    )
+
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes (see module docstring)."""
+    return jax.make_mesh(
+        tuple(axis_shapes),
+        tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )

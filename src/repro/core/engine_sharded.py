@@ -18,6 +18,7 @@ state exists.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # Compatibility re-exports: the version shim lives in repro.compat, the
@@ -45,14 +46,16 @@ def static_meta_of(index: PlaidIndex) -> dict:
     }
 
 
-def shard_index(index: PlaidIndex, n_shards: int):
+def shard_index(index: PlaidIndex, n_shards: int, mesh=None):
     """Partition a globally-built index into equal doc-range shards.
 
     The deployment path: build ONE index (shared centroid space), split by
     document range, stack shard arrays along axis 0 for the sharded engine.
     Per-shard IVFs are recomputed over the shared centroids with LOCAL pids.
     Returns (index_dict, static_meta, docs_per_shard) ready for
-    ``make_sharded_search``.
+    ``make_sharded_search``.  With a ``mesh`` the stacked arrays are placed
+    by ``index_shardings`` straight from the host, so each device holds
+    only its shard; without one they land on the default device.
 
     Shard ``i`` owns global pids ``[i * per, min((i + 1) * per, Nd))``, so
     a sharded pid (``shard * per + local``) IS the original global pid —
@@ -121,10 +124,14 @@ def shard_index(index: PlaidIndex, n_shards: int):
         ("codes", max_nt), ("residuals", max_nt), ("tok_pid", max_nt),
         ("ivf_pids", max_nnz), ("eivf_eids", max_nt),
     ):
-        out[k] = jnp.asarray(np.concatenate([pad(a, per_len) for a in sh[k]]))
+        out[k] = np.concatenate([pad(a, per_len) for a in sh[k]])
     for k in ("doc_offsets", "doc_lens", "ivf_offsets", "ivf_lens",
               "eivf_offsets", "eivf_lens"):
-        out[k] = jnp.asarray(np.concatenate(sh[k]))
+        out[k] = np.concatenate(sh[k])
+    if mesh is None:
+        out = {k: jnp.asarray(v) for k, v in out.items()}
+    else:
+        out = jax.device_put(out, index_shardings(mesh, out))
 
     ivf_cap = int(max(ls.max(initial=1) for ls in sh["ivf_lens"]))
     eivf_cap = int(max(ls.max(initial=1) for ls in sh["eivf_lens"]))

@@ -21,9 +21,26 @@ def num_centroids_for(n_tokens: int, cap: int = 2**18) -> int:
     return int(min(k, cap, max(2, n_tokens)))
 
 
+#: Bound on one assignment chunk's (rows, K) f32 distance tile: 256 MiB.
+#: Row-wise math, so the chunk size never changes a code.
+_CHUNK_ELEMS = 1 << 26
+
+
+def assign_chunk_rows(k: int, chunk: int = 16384) -> int:
+    """Rows per assignment chunk: ``chunk``, shrunk (by powers of two, not
+    below 512) until the (rows, k) distance tile fits ``_CHUNK_ELEMS``."""
+    while chunk > 512 and chunk * k > _CHUNK_ELEMS:
+        chunk //= 2
+    return chunk
+
+
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def _assign_chunked(x: jax.Array, centroids: jax.Array, chunk: int = 16384):
-    """argmin_c ||x - c||^2 computed in row chunks; returns (codes, min_d2)."""
+    """argmin_c ||x - c||^2 computed in row chunks; returns (codes, min_d2).
+
+    ``chunk`` is capped by :func:`assign_chunk_rows`, so at K = 65,536 the
+    distance tile stays at 256 MiB instead of 4 GiB."""
+    chunk = assign_chunk_rows(centroids.shape[0], chunk)
     n = x.shape[0]
     nchunks = (n + chunk - 1) // chunk
     xp = jnp.pad(x, ((0, nchunks * chunk - n), (0, 0)))

@@ -260,7 +260,9 @@ def decompress_score_batched(
     codec = index.codec
     safe = jnp.where(codes_blk >= 0, codes_blk, 0)
     emb = index.centroids[safe] + rc.decompress_residuals(codec, res_blk)
-    scores = jnp.einsum("bqd,bntd->bnqt", qs, emb)  # (B, nd, nq, L)
+    scores = jnp.einsum(  # (B, nd, nq, L) — f32 on every backend
+        "bqd,bntd->bnqt", qs, emb, precision=jax.lax.Precision.HIGHEST
+    )
     scores = jnp.where(tok_valid[:, :, None, :], scores, NEG)
     per_q = scores.max(axis=-1)  # (B, nd, nq)
     per_q = per_q * q_masks[:, None, :]
@@ -283,7 +285,7 @@ def select_finalists_impl(
     alive: jax.Array | None = None,
     keep_blocks: bool = True,  # also return (codes4, tok_valid4) — the
     # per-finalist candidate blocks the UNFUSED stage 4 consumes; the fused
-    # megakernel reads CSR windows directly, so fused callers pass False
+    # tail gathers CSR windows by pid, so fused callers pass False
     nprobe_t: jax.Array | None = None,  # TRACED effective caps <= the
     ndocs_t: jax.Array | None = None,  # static params.nprobe/ndocs (see
     # exec.bucketed: a cap grid reuses one program per pow2 bucket)
@@ -441,9 +443,9 @@ def exact_stage4_impl(
     p = params
     B, n3 = final_pids.shape
     if p.fused:
-        # Fused stage 3-5 tail: gather + decompress + MaxSim in one kernel
-        # straight off the CSR token arrays — the gathered residual block
-        # and the decompressed f32 token tensor never materialize.
+        # Fused stage 3-5 tail: the finalists' CSR windows are gathered by
+        # pid and scored by the stage-4 kernel — the decompressed f32
+        # token tensor never materializes.
         if p.impl == "pallas":
             from repro.kernels import ops as K
 

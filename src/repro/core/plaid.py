@@ -62,9 +62,9 @@ class SearchParams:
     # caps (nprobe=K, cap >= corpus) final ranks are identical because
     # stage 4 rescores exactly.  Distinct from ``score_dtype``, which sets
     # the stage 1-3 approximate-SCORE storage dtype.
-    fused: bool = False  # stage 3-5 tail via the fused gather->decompress->
-    # maxsim megakernel (repro.kernels.fused_score) instead of the
-    # materialized gather + decompress path; rank-identical, the unfused
+    fused: bool = False  # stage 3-5 tail addressed by pid: CSR windows
+    # gathered and scored by the stage-4 kernel (repro.kernels.fused_score)
+    # instead of routing stage 2's blocks; rank-identical, the unfused
     # path survives as the equivalence oracle.
 
     def stage3_docs(self) -> int:

@@ -192,8 +192,8 @@ def _phase_b_impl(
 
     Wraps the slices in a pool-local :class:`PlaidIndex` (IVF fields are
     1-element placeholders — stage 4 never reads them) so
-    ``exact_stage4_impl`` runs unchanged, fused megakernel included: the
-    kernel's scalar-prefetched CSR windows work over ANY token array.
+    ``exact_stage4_impl`` runs unchanged, fused tail included: its CSR
+    window gather works over ANY token array.
     """
     global _N_TRACES_B
     _N_TRACES_B += 1

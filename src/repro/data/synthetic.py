@@ -84,6 +84,125 @@ def queries_from_docs(
     return np.stack(qs), np.asarray(golds)
 
 
+class CorpusStream:
+    """Corpus-scale version of :func:`embedding_corpus`, generated in chunks.
+
+    The same concept geometry, with three differences that matter at the
+    scale of a deployment (1M+ passages, where the corpus is 32+ GB of f32
+    and never sits whole on the host):
+
+    * heavy-tailed passage lengths: log-normal with mean ``mean_len``
+      (MS MARCO's 68), clipped to ``[min_len, max_len]``;
+    * each passage draws ``ceil(len / repeat)`` concepts and repeats them,
+      so an IVF list holds about ``repeat`` tokens per passage;
+    * tokens are made on the device: a chunk's payload is its (T,) concept
+      ids plus the chunk index, and :attr:`encode` maps it to (T, dim)
+      unit-norm embeddings — the ``encode_fn`` of a
+      ``repro.build.chunks.ChunkStream``.
+
+    Every chunk holds exactly ``chunk_tokens`` tokens and ends on a passage
+    boundary (its last passage takes the remainder), so the build compiles
+    one program; chunks are added until at least ``n_docs`` passages exist.
+    Chunk ``c`` is a pure function of ``(seed, c)``.
+    """
+
+    def __init__(
+        self,
+        n_docs: int,
+        dim: int = 128,
+        *,
+        mean_len: float = 68.0,
+        min_len: int = 8,
+        max_len: int = 128,
+        n_topics: int = 32,
+        n_concepts: int = 1 << 16,
+        repeat: float = 3.0,
+        noise: float = 0.35,
+        chunk_tokens: int = 1 << 18,
+        seed: int = 0,
+    ):
+        import functools
+
+        import jax
+
+        self.dim, self.seed = dim, seed
+        self.min_len, self.max_len, self.mean_len = min_len, max_len, mean_len
+        self.n_topics, self.n_concepts, self.repeat = n_topics, n_concepts, repeat
+        self.chunk_tokens = chunk_tokens
+        self.chunk_lens, n = [], 0
+        while n < max(1, n_docs):
+            self.chunk_lens.append(self._lens(len(self.chunk_lens)))
+            n += len(self.chunk_lens[-1])
+        n_chunks = len(self.chunk_lens)
+        self.chunk_pid0 = np.cumsum([0] + [len(x) for x in self.chunk_lens])
+        self.n_docs = int(self.chunk_pid0[-1])
+        self.n_tokens = n_chunks * chunk_tokens
+        self.encode = jax.jit(
+            functools.partial(
+                _corpus_tokens, seed=seed, n_concepts=n_concepts, dim=dim,
+                noise=noise,
+            )
+        )
+
+    def _rng(self, c: int, stream: int):
+        return np.random.default_rng((self.seed, c, stream))
+
+    def _lens(self, c: int) -> np.ndarray:
+        rng = self._rng(c, 0)
+        sigma = 0.6
+        mu = np.log(self.mean_len) - sigma**2 / 2
+        draw = np.clip(
+            np.rint(rng.lognormal(mu, sigma, 2 * self.chunk_tokens // self.min_len)),
+            self.min_len, self.max_len,
+        ).astype(np.int64)
+        ends = np.cumsum(draw)
+        n = int(np.searchsorted(ends, self.chunk_tokens, side="right"))
+        lens = draw[:n]
+        rest = self.chunk_tokens - int(lens.sum())
+        return np.append(lens, rest).astype(np.int32) if rest else lens.astype(np.int32)
+
+    def payload(self, c: int) -> np.ndarray:
+        """Chunk ``c``'s (T + 1,) int32 payload: concept ids, chunk index."""
+        lens = self.chunk_lens[c]
+        rng = self._rng(c, 1)
+        nd = len(lens)
+        pool = self.n_concepts // self.n_topics
+        topics = rng.integers(0, self.n_topics, nd)
+        m = np.ceil(lens / self.repeat).astype(np.int64)
+        slots = rng.integers(0, pool, (nd, int(m.max())))
+        tok_doc = np.repeat(np.arange(nd), lens)
+        r = (rng.random(tok_doc.shape[0]) * m[tok_doc]).astype(np.int64)
+        ids = topics[tok_doc] * pool + slots[tok_doc, r]
+        return np.append(ids, c).astype(np.int32)
+
+    def chunks(self):
+        """Fresh iterator of ``(payload, doc_lens)`` chunks (re-iterable)."""
+        for c, lens in enumerate(self.chunk_lens):
+            yield self.payload(c), lens
+
+    def docs(self, c: int) -> list[np.ndarray]:
+        """Chunk ``c``'s passages as host (len_i, dim) arrays; passage ``j``
+        of the list has global pid ``chunk_pid0[c] + j``."""
+        emb = np.asarray(self.encode(self.payload(c)))
+        return np.split(emb, np.cumsum(self.chunk_lens[c])[:-1])
+
+
+def _corpus_tokens(payload, *, seed, n_concepts, dim, noise):
+    """(T + 1,) payload -> (T, dim) unit-norm token embeddings (device)."""
+    import jax
+    import jax.numpy as jnp
+
+    k_c, k_n = jax.random.split(jax.random.PRNGKey(seed))
+    concepts = jax.random.normal(k_c, (n_concepts, dim), jnp.float32)
+    concepts = concepts / jnp.linalg.norm(concepts, axis=-1, keepdims=True)
+    ids = payload[:-1]
+    u = jax.random.normal(
+        jax.random.fold_in(k_n, payload[-1]), (ids.shape[0], dim), jnp.float32
+    )
+    e = concepts[ids] + (noise / np.sqrt(dim)) * u
+    return e / jnp.linalg.norm(e, axis=-1, keepdims=True)
+
+
 # --------------------------------------------------------------------------
 # LM token streams (zipfian synthetic corpus)
 # --------------------------------------------------------------------------

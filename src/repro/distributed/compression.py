@@ -64,9 +64,7 @@ def compressed_psum(x: jax.Array, axis_name: str, block: int = 256):
     quantize chunk -> all_gather(int8) -> dequant.  Exact-size collectives;
     falls back to plain psum when the axis has a single member.
     """
-    from repro.compat import axis_size
-
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     if n_dev == 1:
         return x
     shape = x.shape

@@ -127,7 +127,9 @@ class LiveExecutor:
 
         st = self._base_shards
         if st is None or st["sid"] != base_sid:
-            idx_dict, meta, per = shard_index(base, self.n_shards)
+            idx_dict, meta, per = shard_index(
+                base, self.n_shards, mesh=self.mesh
+            )
             st = dict(sid=base_sid, idx=idx_dict, meta=meta, per=per, fns={})
             self._base_shards = st
         fn_key = (interpret, funnel)

@@ -71,26 +71,18 @@ def index_as_dict(index: PlaidIndex):
     }
 
 
-def index_shardings(mesh, index: PlaidIndex):
-    """NamedShardings for a globally-assembled sharded index.
+def index_shardings(mesh, fields) -> dict:
+    """Field name -> ``NamedSharding`` for a shard-stacked index dict.
 
-    Doc-partitioned arrays shard their leading axis over all mesh axes;
-    centroid-space arrays (centroids, codec tables, IVF offsets) replicate.
+    Doc-partitioned arrays shard their leading axis over the mesh's doc
+    axes, so each device holds only its own shard; centroid-space arrays
+    (centroids, codec tables) replicate.
     """
-    ax = doc_axes(mesh)
-    doc = NamedSharding(mesh, P(ax))
+    doc = NamedSharding(mesh, P(doc_axes(mesh)))
     rep = NamedSharding(mesh, P())
-    return PlaidIndex(
-        **{
-            name: (rep if name in _REPLICATED_FIELDS else doc)
-            for name in index_as_dict(index)
-        },
-        **{
-            f.name: getattr(index, f.name)
-            for f in dataclasses.fields(PlaidIndex)
-            if f.metadata.get("static")
-        },
-    )
+    return {
+        name: (rep if name in _REPLICATED_FIELDS else doc) for name in fields
+    }
 
 
 def make_sharded_search(

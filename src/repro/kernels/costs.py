@@ -9,11 +9,11 @@ every machine and jax version, which is what makes the per-kernel
 ``hbm_bytes`` records in BENCH JSON safe to hard-gate in CI
 (``benchmarks.bench_diff``).
 
-The two composite stage-3-5 entries are the fused-vs-unfused headline: the
-unfused tail materializes the gathered residual/code/validity blocks in HBM
-between the XLA gather and the decompress kernel (write + re-read), the
-fused megakernel streams them through VMEM once.  ``tests/test_fused.py``
-pins ``fused < unfused`` as an invariant.
+The two composite stage-3-5 entries compare the tails: both gather their
+blocks in XLA and score them with the same stage-4 kernel; the unfused tail
+routes stage 2's codes/validity blocks (read + write each), the fused one
+gathers code windows by pid and derives validity from the lengths (write
+only).  ``tests/test_fused.py`` pins ``fused < unfused``.
 
 Flops count the MXU matmuls only (the unpack/select chains are cheap VPU
 integer ops, identical between paths, and would only pad both sides).
@@ -56,21 +56,20 @@ def centroid_interaction_batched_cost(
     *, B: int, nd: int, L: int, K: int, nq: int, doc_block: int = 32
 ) -> dict:
     """``kernels.maxsim.centroid_interaction_batched_pallas``: grid
-    (B, nd/doc_block); s_cq / keep / qmask resident per lane, codes blocks
-    stream."""
+    (B, nd/doc_block); the XLA-gathered (doc_block, nq, L) f32 token-score
+    blocks stream, q_mask resident per lane.  ``K`` does not enter: the
+    score table never reaches the kernel."""
     blocks = _ceil_div(nd, doc_block)
     nd_p = blocks * doc_block
     hbm = pallas_block_traffic(
         (B, blocks),
         in_specs=[
-            (K * nq * _F32, lambda b, i: (b, 0, 0)),  # s_cq lane tile
-            (doc_block * L * _I32, lambda b, i: (b, i, 0)),  # codes block
-            (K * 1, lambda b, i: (b, 0, 0)),  # keep_centroid (bool)
+            (doc_block * nq * L * _F32, lambda b, i: (b, i, 0, 0)),  # scores
             (nq * _F32, lambda b, i: (b, 0, 0)),  # q_mask
         ],
         out_specs=[(doc_block * _F32, lambda b, i: (b, i, 0))],
     )
-    # gather-of-score-rows + masked max: no dot; count the mask-weighted sum
+    # masked max + mask-weighted sum: no dot
     flops = 2.0 * B * nd_p * L * nq
     return dict(hbm_bytes=hbm, flops=flops)
 
@@ -88,19 +87,21 @@ def decompress_and_score_batched_cost(
     doc_block: int = 8,
 ) -> dict:
     """``kernels.decompress.decompress_and_score_batched_pallas``: grid
-    (B, nd/doc_block); q tile resident per lane, centroids/weights resident
-    across the whole grid, codes/residual/validity blocks stream."""
+    (B, nd/doc_block); q tile resident per lane, weights resident across
+    the grid; the XLA-gathered f32 centroid rows, packed residuals and
+    validity stream.  ``K`` does not enter: the centroid table never
+    reaches the kernel."""
     blocks = _ceil_div(nd, doc_block)
     nd_p = blocks * doc_block
+    rows = doc_block * L
     hbm = pallas_block_traffic(
         (B, blocks),
         in_specs=[
             (nq * d * _F32, lambda b, i: (b, 0, 0)),  # q lane tile
             (nq * _F32, lambda b, i: (b, 0, 0)),  # q_mask
-            (doc_block * L * _I32, lambda b, i: (b, i, 0)),  # codes
-            (doc_block * L * pd * _U8, lambda b, i: (b, i, 0)),  # residuals
-            (doc_block * L * _I32, lambda b, i: (b, i, 0)),  # tok_valid i32
-            (K * d * _F32, lambda b, i: (0, 0)),  # centroids
+            (rows * d * _F32, lambda b, i: (b, i, 0)),  # centroid rows
+            (rows * pd * _U8, lambda b, i: (b, i, 0)),  # residuals
+            (rows * _I32, lambda b, i: (b, i, 0)),  # tok_valid i32
             ((2**nbits) * _F32, lambda b, i: (0, 0)),  # weights
         ],
         out_specs=[(doc_block * _F32, lambda b, i: (b, i, 0))],
@@ -109,28 +110,35 @@ def decompress_and_score_batched_cost(
     return dict(hbm_bytes=hbm, flops=flops)
 
 
+def _stage4_centroid_gather_bytes(*, B: int, n3: int, L: int, d: int) -> int:
+    """The XLA centroid-row gather feeding the stage-4 kernel: read the
+    codes block, write the (B, n3, L, d) f32 rows (the rows it reads from
+    the centroid table equal the rows it writes)."""
+    return B * n3 * L * (_I32 + 2 * d * _F32)
+
+
 def gather_decompress_maxsim_cost(
     *, B: int, n3: int, L: int, pd: int, K: int, d: int, nq: int, nbits: int
 ) -> dict:
-    """``kernels.fused_score.gather_decompress_maxsim_pallas``: grid
-    (B, n3), one finalist passage per step; CSR windows stream straight from
-    the token arrays (scalar-prefetched element offsets), query tile
-    resident per lane, centroids/weights resident across the grid."""
-    hbm = pallas_block_traffic(
-        (B, n3),
-        in_specs=[
-            (nq * d * _F32, lambda b, i: (b, 0, 0)),  # q lane tile
-            (nq * _F32, lambda b, i: (b, 0, 0)),  # q_mask
-            (L * _I32, lambda b, i: (b, i)),  # codes CSR window
-            (L * pd * _U8, lambda b, i: (b, i)),  # residual CSR window
-            (K * d * _F32, lambda b, i: (0, 0)),  # centroids
-            ((2**nbits) * _F32, lambda b, i: (0, 0)),  # weights
-        ],
-        out_specs=[(_F32, lambda b, i: (b, i))],
-        scalar_bytes=3 * B * n3 * _I32,  # starts / row0 / lens tables
+    """``kernels.fused_score.gather_decompress_maxsim_pallas``: the
+    finalists' CSR windows gathered by pid (codes + residuals read once
+    from the token arrays and written as blocks; validity computed from
+    the lengths, write only), the centroid-row gather, then the stage-4
+    kernel."""
+    gather_bytes = (
+        2 * B * n3 * L * pd * _U8  # residual windows: CSR read + write
+        + 2 * B * n3 * L * _I32  # code windows: CSR read + write
+        + B * n3 * L * _I32  # validity from lens: write only
     )
-    flops = 2.0 * B * n3 * L * d * nq
-    return dict(hbm_bytes=hbm, flops=flops)
+    kern = decompress_and_score_batched_cost(
+        B=B, nd=n3, L=L, pd=pd, K=K, d=d, nq=nq, nbits=nbits
+    )
+    return dict(
+        hbm_bytes=gather_bytes
+        + _stage4_centroid_gather_bytes(B=B, n3=n3, L=L, d=d)
+        + kern["hbm_bytes"],
+        flops=kern["flops"],
+    )
 
 
 def unfused_stage345_cost(
@@ -145,10 +153,10 @@ def unfused_stage345_cost(
     nbits: int,
     doc_block: int = 8,
 ) -> dict:
-    """The materialized stage-3-5 tail the megakernel replaces: the XLA
-    residual gather (read the selected CSR bytes, WRITE the routed block),
-    the codes/validity take-alongs (read + write each), then the stage-4
-    decompress kernel re-reading everything it just wrote."""
+    """The stage-3-5 tail fed by stage 2's blocks: the XLA residual gather
+    (read the selected CSR bytes, WRITE the routed block), the
+    codes/validity take-alongs (read + write each), the centroid-row
+    gather, then the stage-4 kernel re-reading what they wrote."""
     gather_bytes = (
         2 * B * n3 * L * pd * _U8  # res_blk: CSR read + routed-block write
         + 2 * B * n3 * L * _I32  # codes4 take_along: read + write
@@ -159,14 +167,17 @@ def unfused_stage345_cost(
         doc_block=doc_block,
     )
     return dict(
-        hbm_bytes=gather_bytes + kern["hbm_bytes"], flops=kern["flops"]
+        hbm_bytes=gather_bytes
+        + _stage4_centroid_gather_bytes(B=B, n3=n3, L=L, d=d)
+        + kern["hbm_bytes"],
+        flops=kern["flops"],
     )
 
 
 def fused_stage345_cost(
     *, B: int, n3: int, L: int, pd: int, K: int, d: int, nq: int, nbits: int
 ) -> dict:
-    """Fused stage-3-5 tail: exactly the megakernel — no intermediate."""
+    """Fused stage-3-5 tail: the pid-addressed gather + stage-4 kernel."""
     return gather_decompress_maxsim_cost(
         B=B, n3=n3, L=L, pd=pd, K=K, d=d, nq=nq, nbits=nbits
     )
@@ -218,18 +229,15 @@ def resident_payload_bytes(*, num_tokens: int, pd: int) -> int:
 # Kernel <-> cost-record registry (completeness-linted in CI)
 # --------------------------------------------------------------------------
 #: Every ``pallas_call``-launching function in ``repro.kernels`` maps to the
-#: cost function modelling its traffic.  The single-query kernels share the
-#: batched model (they are its B=1 degenerate case — same grid per lane,
-#: same block specs).  ``tests/test_obs.py`` AST-scans the kernels package
+#: cost function modelling its traffic (the single-query wrappers in
+#: ``kernels.ops`` launch the batched kernels at B=1).
+#: ``tests/test_obs.py`` AST-scans the kernels package
 #: and fails when a new pallas_call site appears in neither table below:
 #: a kernel outside the traffic model is a kernel CI cannot gate.
 KERNEL_COSTS = {
-    "centroid_interaction_pallas": centroid_interaction_batched_cost,
     "centroid_interaction_batched_pallas": centroid_interaction_batched_cost,
     "decompress_residuals_pallas": decompress_residuals_cost,
-    "decompress_and_score_pallas": decompress_and_score_batched_cost,
     "decompress_and_score_batched_pallas": decompress_and_score_batched_cost,
-    "gather_decompress_maxsim_pallas": gather_decompress_maxsim_cost,
 }
 
 #: Deliberately unmodelled pallas_call sites, each with its reason.  Adding

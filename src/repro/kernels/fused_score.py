@@ -1,79 +1,24 @@
-"""Fused gather -> decompress -> MaxSim Pallas megakernel (stage 3-5 tail).
+"""Stage-3-5 tail straight off the CSR token arrays: gather -> decompress ->
+MaxSim for the finalist passages, addressed by pid.
 
-The unfused stage-4 path materializes TWO intermediates in HBM per batch:
-the gathered packed-residual block ``(B*n3, doc_maxlen, pd)`` u8 written by
-``scoring.gather_doc_tokens`` and then re-read by the decompress kernel, and
-(through XLA) the routed codes/validity blocks.  At paper scale the gathered
-blocks dominate stage-4 traffic — which is exactly why PLAID ships a
-dedicated decompression kernel (paper §4.5).
+The unfused tail consumes the codes/validity blocks stage 2 already
+gathered and gathers only the residuals; this entry point gathers each
+finalist's codes AND packed residuals directly from the index's CSR arrays
+and scores them with the stage-4 kernel
+(``decompress.decompress_and_score_batched_pallas``).
 
-This kernel removes the round trip entirely: the grid is ``(B, n3)`` — one
-finalist passage per step — and each step DMAs its passage's packed codes +
-residual bytes straight out of the index's CSR-backed token arrays via
-*scalar-prefetched* element offsets (``pltpu.PrefetchScalarGridSpec`` +
-``pl.Unblocked`` indexing).  Inside the tile the b-bit fields are expanded
-with the shared shift/mask chain (``decompress._unpack``), the embedding is
-reconstructed in-register as ``centroids[code] + weights[idx]``, and the
-per-query-token running max for MaxSim accumulates in the same tile loop.
-Nothing wider than the ``(B, n3)`` score matrix is ever written back.
-
-CSR windows are fixed-size (``doc_maxlen`` rows) so shapes stay static; a
-passage near the end of the token array gets a window clamped back into
-range with its valid-row interval ``[row0, row0 + len)`` shifted to match
-(rows outside the interval belong to neighboring passages and are masked to
-``NEG`` before the max).  Padded ``pid == -1`` lanes carry ``len == 0`` —
-every row masks away and the caller's final ``where`` pins their score.
+The windows are gathered by XLA.  Reading them inside the kernel through
+``pl.Element`` row offsets does not lower for the TPU: Mosaic requires a
+window's first row to be a multiple of the 8-row tiling, CSR windows start
+at any token, and no aligned window can reach the last rows of a token
+array whose length is not a multiple of 8.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.constants import NEG
-from repro.kernels.decompress import _unpack
-from repro.kernels.dispatch import resolve_interpret
-
-
-def _fused_kernel(
-    # --- scalar-prefetch refs (one (B, n3) i32 table each) ---
-    starts_ref,  # clamped window start (element row into the token arrays)
-    row0_ref,  # first valid row inside the window
-    lens_ref,  # true passage length (0 for pid == -1 pads)
-    # --- array blocks ---
-    q_ref,  # (1, nq, d) f32 — this lane's query tile, resident per lane
-    qmask_ref,  # (1, 1, nq)
-    codes_ref,  # (L, 1) i32 — unblocked CSR window at starts[b, i]
-    res_ref,  # (L, pd) u8 — unblocked CSR window at starts[b, i]
-    cent_ref,  # (K, d) f32 — resident across the whole grid
-    weights_ref,  # (2^b, 1) f32
-    out_ref,  # (1, 1) f32
-    *,
-    nbits: int,
-):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    r0 = row0_ref[b, i]
-    ln = lens_ref[b, i]
-    q = q_ref[0]  # (nq, d)
-    codes = codes_ref[...][:, 0]  # (L,) — real centroid ids (never -1)
-    L = codes.shape[0]
-    packed = res_ref[...].astype(jnp.int32)  # (L, pd)
-    idx = _unpack(packed, nbits)  # (L, d) bucket indices
-    w = weights_ref[...][:, 0]
-    resid = jnp.zeros(idx.shape, jnp.float32)
-    for v in range(w.shape[0]):  # 2^b <= 16: unrolled select chain, pure VPU
-        resid = jnp.where(idx == v, w[v], resid)
-    emb = jnp.take(cent_ref[...], codes, axis=0) + resid  # (L, d) in-register
-    scores = emb @ q.T  # (L, nq) — MXU matmul
-    pos = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
-    valid = (pos >= r0) & (pos < r0 + ln)  # rows of THIS passage only
-    scores = jnp.where(valid, scores, NEG)
-    per_q = scores.max(axis=0)  # (nq,) running max over the passage's tokens
-    out_ref[0, 0] = jnp.sum(per_q * qmask_ref[0, 0])
+from repro.core import scoring
+from repro.kernels.decompress import decompress_and_score_batched_pallas
 
 
 def gather_decompress_maxsim_pallas(
@@ -91,68 +36,25 @@ def gather_decompress_maxsim_pallas(
     doc_maxlen: int,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Exact MaxSim scores (B, n3) for the finalist passages, gathered and
-    decompressed inside one kernel.  Scores for ``pid == -1`` lanes are
-    garbage-free ``nq * NEG``-ish values the caller overrides; every valid
-    lane matches ``decompress_and_score_batched`` bit-for-bit."""
-    interpret = resolve_interpret(interpret)
+    """Exact MaxSim scores (B, n3) for the finalist passages.  Scores for
+    ``pid == -1`` lanes are ``nq * NEG``-ish values the caller overrides;
+    every valid lane matches ``decompress_and_score_batched``."""
     B, n3 = final_pids.shape
-    L = doc_maxlen
-    Nt = codes_tok.shape[0]
-    pd = residuals_tok.shape[1]
-    K, d = centroids.shape
-    nq = qs.shape[1]
-    if Nt < L:  # tiny corpus: the fixed window must fit inside the array
-        pad = L - Nt
-        codes_tok = jnp.pad(codes_tok, (0, pad))
-        residuals_tok = jnp.pad(residuals_tok, ((0, pad), (0, 0)))
-        Nt = L
-
-    # Window math (XLA level, tiny): a clamped fixed-size window plus the
-    # valid-row interval it implies.  See module docstring.
-    safe_pid = jnp.where(final_pids >= 0, final_pids, 0)
-    start_true = doc_offsets[safe_pid].astype(jnp.int32)  # (B, n3)
-    lens = jnp.where(final_pids >= 0, doc_lens[safe_pid], 0).astype(jnp.int32)
-    starts = jnp.clip(start_true, 0, Nt - L)
-    row0 = start_true - starts
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, n3),
-        in_specs=[
-            pl.BlockSpec((1, nq, d), lambda b, i, st, r0, ln: (b, 0, 0)),
-            pl.BlockSpec((1, 1, nq), lambda b, i, st, r0, ln: (b, 0, 0)),
-            pl.BlockSpec(
-                (L, 1),
-                lambda b, i, st, r0, ln: (st[b, i], 0),
-                indexing_mode=pl.Unblocked(),
-            ),
-            pl.BlockSpec(
-                (L, pd),
-                lambda b, i, st, r0, ln: (st[b, i], 0),
-                indexing_mode=pl.Unblocked(),
-            ),
-            pl.BlockSpec((K, d), lambda b, i, st, r0, ln: (0, 0)),
-            pl.BlockSpec(
-                (weights.shape[0], 1), lambda b, i, st, r0, ln: (0, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, i, st, r0, ln: (b, i)),
+    flat = final_pids.reshape(-1)
+    codes, valid = scoring.gather_doc_tokens(
+        codes_tok, doc_offsets, doc_lens, flat, doc_maxlen, fill=-1
     )
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, nbits=nbits),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, n3), jnp.float32),
+    res, _ = scoring.gather_doc_tokens(
+        residuals_tok, doc_offsets, doc_lens, flat, doc_maxlen, fill=0
+    )
+    return decompress_and_score_batched_pallas(
+        qs,
+        q_masks,
+        codes.reshape(B, n3, doc_maxlen),
+        res.reshape(B, n3, doc_maxlen, -1),
+        valid.reshape(B, n3, doc_maxlen),
+        centroids,
+        weights,
+        nbits=nbits,
         interpret=interpret,
-    )(
-        starts,
-        row0,
-        lens,
-        qs.astype(jnp.float32),
-        q_masks.astype(jnp.float32)[:, None, :],
-        codes_tok.astype(jnp.int32)[:, None],
-        residuals_tok,
-        centroids.astype(jnp.float32),
-        weights.astype(jnp.float32)[:, None],
     )
-    return out

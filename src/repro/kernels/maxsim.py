@@ -1,17 +1,18 @@
-"""Pallas TPU kernel: packed padding-free centroid interaction (paper §4.5).
+"""Pallas TPU kernel: centroid interaction over candidate passages (§4.5).
 
 The paper's C++ kernel loops over each passage's packed token vectors and
-keeps an O(|Q|) running-max accumulator per passage, avoiding the padded
-(nd, L, |Q|) 3-D score tensor in memory.  The TPU-native re-derivation
-(DESIGN §3): grid over *blocks of candidate passages*; each block gathers the
-pre-computed query-centroid score rows ``S_cq[code]`` for its tokens straight
-into VMEM, reduces max-over-tokens / sum-over-query-tokens in-register, and
-writes only the (block,) score vector to HBM.  The full 3-D tensor exists
-only tile-by-tile in VMEM — same insight, vectorized over the 8x128 VPU.
+keeps an O(|Q|) running-max accumulator per passage.  On the TPU the
+per-token row gather ``S_cq[code]`` cannot run inside a Mosaic kernel (its
+gather rule takes only a 2-D ``take_along_axis`` of identical shapes), so
+the gather is hoisted into XLA — the same split
+``pipeline.gather_candidate_tokens_shared`` makes for the codes — and the
+kernel does the reduction: grid ``(B, doc_blocks)``, each step a
+``(BD, nq, L)`` tile of gathered scores reduced max-over-tokens, then
+relu, query mask and sum-over-query-tokens, writing only ``(BD,)`` scores.
 
-VMEM budget per block (defaults, f32): S_cq 64Kx32 would not fit — callers
-at large K use the chunked-K variant in ops.py; at the paper's MS MARCO v1
-scale (K=2^16, nq=32) bf16 scores fit in ~4 MB.
+Layout: tokens on the lane axis (``L = doc_maxlen`` is 128 at cell widths)
+and query tokens on sublanes, so neither the tile nor its HBM copy pads
+``nq = 32`` out to 128 lanes.
 """
 from __future__ import annotations
 
@@ -23,87 +24,27 @@ from repro.constants import NEG
 from repro.kernels.dispatch import resolve_interpret
 
 
-def _centroid_interaction_kernel(
-    s_cq_ref,  # (K, nq) f32 — resident
-    codes_ref,  # (BD, L) i32 block
-    keep_ref,  # (K, 1) i32 (bool as int) — resident
-    q_mask_ref,  # (1, nq) f32 — resident
-    out_ref,  # (BD, 1) f32 block
-):
-    codes = codes_ref[...]  # (BD, L)
-    bd, L = codes.shape
-    nq = s_cq_ref.shape[1]
+def gather_token_scores(s_cq, codes, keep):
+    """(B, K, nq) scores x (B, nd, L) codes -> (B, nd, nq, L) f32 token
+    scores, ``NEG`` where the token is padding or its centroid is pruned."""
+    B, nd, L = codes.shape
     valid = codes >= 0
-    safe = jnp.where(valid, codes, 0).reshape(-1)
-    # Gather score rows for every token in the block: (BD*L, nq).
-    tok_scores = jnp.take(s_cq_ref[...], safe, axis=0)
-    kept = jnp.take(keep_ref[...][:, 0], safe, axis=0) > 0
-    mask = valid.reshape(-1) & kept
-    tok_scores = jnp.where(mask[:, None], tok_scores, NEG)
-    per_q = tok_scores.reshape(bd, L, nq).max(axis=1)  # (BD, nq)
-    per_q = jnp.maximum(per_q, 0.0)
-    out_ref[...] = (per_q * q_mask_ref[...]).sum(axis=-1, keepdims=True)
-
-
-def centroid_interaction_pallas(
-    s_cq: jax.Array,  # (K, nq)
-    codes: jax.Array,  # (nd, L) i32, -1 padding
-    keep: jax.Array,  # (K,) bool
-    q_mask: jax.Array,  # (nq,)
-    *,
-    doc_block: int = 32,
-    interpret: bool | None = None,
-) -> jax.Array:
-    interpret = resolve_interpret(interpret)
-    nd, L = codes.shape
-    K, nq = s_cq.shape
-    pad = (-nd) % doc_block
-    if pad:
-        codes = jnp.pad(codes, ((0, pad), (0, 0)), constant_values=-1)
-    grid = ((nd + pad) // doc_block,)
-    out = pl.pallas_call(
-        _centroid_interaction_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((K, nq), lambda i: (0, 0)),
-            pl.BlockSpec((doc_block, L), lambda i: (i, 0)),
-            pl.BlockSpec((K, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, nq), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((doc_block, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nd + pad, 1), jnp.float32),
-        interpret=interpret,
-    )(
-        s_cq.astype(jnp.float32),
-        codes,
-        keep.astype(jnp.int32)[:, None],
-        q_mask.astype(jnp.float32)[None, :],
+    safe = jnp.where(valid, codes, 0).reshape(B, nd * L)
+    tok = jnp.take_along_axis(s_cq, safe[..., None], axis=1)  # (B, nd*L, nq)
+    kept = jnp.take_along_axis(keep, safe, axis=1).reshape(B, nd, L)
+    tok = jnp.where(
+        (valid & kept)[..., None], tok.reshape(B, nd, L, -1).astype(jnp.float32),
+        NEG,
     )
-    return out[:nd, 0]
+    return tok.transpose(0, 1, 3, 2)
 
 
-# --------------------------------------------------------------------------
-# Batched variant: grid (B, doc_blocks)
-# --------------------------------------------------------------------------
-def _centroid_interaction_batched_kernel(
-    s_cq_ref,  # (1, K, nq) f32 — this lane's score matrix, resident per lane
-    codes_ref,  # (1, BD, L) i32 block
-    keep_ref,  # (1, K, 1) i32 — this lane's centroid-pruning mask
+def _centroid_interaction_kernel(
+    tok_ref,  # (1, BD, nq, L) f32 gathered token scores
     q_mask_ref,  # (1, 1, nq) f32
-    out_ref,  # (1, BD, 1) f32 block
+    out_ref,  # (1, BD, 1) f32
 ):
-    codes = codes_ref[0]  # (BD, L)
-    bd, L = codes.shape
-    s_cq = s_cq_ref[0]  # (K, nq)
-    nq = s_cq.shape[1]
-    valid = codes >= 0
-    safe = jnp.where(valid, codes, 0).reshape(-1)
-    tok_scores = jnp.take(s_cq, safe, axis=0)  # (BD*L, nq)
-    kept = jnp.take(keep_ref[0][:, 0], safe, axis=0) > 0
-    mask = valid.reshape(-1) & kept
-    tok_scores = jnp.where(mask[:, None], tok_scores, NEG)
-    per_q = tok_scores.reshape(bd, L, nq).max(axis=1)  # (BD, nq)
-    per_q = jnp.maximum(per_q, 0.0)
+    per_q = jnp.maximum(tok_ref[0].max(axis=-1), 0.0)  # (BD, nq)
     out_ref[0] = (per_q * q_mask_ref[0]).sum(axis=-1, keepdims=True)
 
 
@@ -116,34 +57,24 @@ def centroid_interaction_batched_pallas(
     doc_block: int = 32,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Batch-first stage-2/3 interaction: one kernel launch for the whole
-    (B, nd) candidate block.  The grid is (B, doc_blocks) with the doc axis
-    innermost, so each lane's S_cq / keep / q_mask tiles load into VMEM once
-    and stay resident across all of that lane's doc blocks (the vmap-of-
-    single-query path re-fetched them per lane per block)."""
+    """Batch-first stage-2/3 interaction: (B, nd) approximate scores from
+    one kernel launch with grid (B, doc_blocks)."""
     interpret = resolve_interpret(interpret)
     B, nd, L = codes.shape
-    _, K, nq = s_cq.shape
+    nq = s_cq.shape[2]
     pad = (-nd) % doc_block
     if pad:
         codes = jnp.pad(codes, ((0, 0), (0, pad), (0, 0)), constant_values=-1)
-    grid = (B, (nd + pad) // doc_block)
+    tok = gather_token_scores(s_cq, codes, keep)
     out = pl.pallas_call(
-        _centroid_interaction_batched_kernel,
-        grid=grid,
+        _centroid_interaction_kernel,
+        grid=(B, (nd + pad) // doc_block),
         in_specs=[
-            pl.BlockSpec((1, K, nq), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, doc_block, L), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, K, 1), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, doc_block, nq, L), lambda b, i: (b, i, 0, 0)),
             pl.BlockSpec((1, 1, nq), lambda b, i: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, doc_block, 1), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nd + pad, 1), jnp.float32),
         interpret=interpret,
-    )(
-        s_cq.astype(jnp.float32),
-        codes,
-        keep.astype(jnp.int32)[..., None],
-        q_mask.astype(jnp.float32)[:, None, :],
-    )
+    )(tok, q_mask.astype(jnp.float32)[:, None, :])
     return out[:, :nd, 0]

@@ -44,19 +44,20 @@ def centroid_interaction(
     interpret: bool | None = None,
     doc_block: int = 32,
 ) -> jax.Array:
-    """Engine-compatible signature (matches ``scoring.centroid_interaction``)."""
+    """Engine-compatible signature (matches ``scoring.centroid_interaction``):
+    the batched kernel over a batch of one."""
     if q_mask is None:
         q_mask = jnp.ones(s_cq.shape[1], jnp.float32)
     if keep_centroid is None:
         keep_centroid = jnp.ones(s_cq.shape[0], bool)
-    return _ms.centroid_interaction_pallas(
-        s_cq,
-        codes,
-        keep_centroid,
-        q_mask,
+    return _ms.centroid_interaction_batched_pallas(
+        s_cq[None],
+        codes[None],
+        keep_centroid[None],
+        q_mask[None],
         doc_block=doc_block,
         interpret=resolve_interpret(interpret),
-    )
+    )[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "doc_block"))
@@ -119,18 +120,19 @@ def decompress_and_score(
     interpret: bool | None = None,
     doc_block: int = 8,
 ) -> jax.Array:
-    return _dec.decompress_and_score_pallas(
-        q,
-        q_mask,
-        codes,
-        packed_res,
-        tok_valid,
+    """Single-query stage 4: the batched kernel over a batch of one."""
+    return _dec.decompress_and_score_batched_pallas(
+        q[None],
+        q_mask[None],
+        codes[None],
+        packed_res[None],
+        tok_valid[None],
         centroids,
         weights,
         nbits=nbits,
         doc_block=doc_block,
         interpret=resolve_interpret(interpret),
-    )
+    )[0]
 
 
 @functools.partial(jax.jit, static_argnames=("nbits", "interpret", "doc_block"))
@@ -180,10 +182,9 @@ def gather_decompress_maxsim(
     doc_maxlen: int,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """The fused stage-3-5 megakernel: gather + decompress + exact MaxSim in
-    one launch (grid (B, n3), scalar-prefetched CSR offsets) — the gathered
-    residual block and the decompressed f32 token tensor never reach HBM.
-    Returns (B, n3) exact scores (pid == -1 lanes are the caller's to pin).
+    """The stage-3-5 tail addressed by pid: the finalists' CSR windows are
+    gathered and scored by the stage-4 kernel.  Returns (B, n3) exact
+    scores (pid == -1 lanes are the caller's to pin).
     """
     return _fs.gather_decompress_maxsim_pallas(
         qs,

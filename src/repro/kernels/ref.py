@@ -51,7 +51,7 @@ def gather_decompress_maxsim_ref(
     nbits: int,
     doc_maxlen: int,
 ) -> jax.Array:
-    """Reference interpreter path for the fused stage-3-5 megakernel
+    """Reference path for the pid-addressed stage-3-5 tail
     (``fused_score.gather_decompress_maxsim_pallas``): gather the finalist
     passages' codes + packed residuals straight from the CSR token arrays,
     decompress, and MaxSim — same op order as the unfused
@@ -73,7 +73,9 @@ def gather_decompress_maxsim_ref(
     safe = jnp.where(codes_blk >= 0, codes_blk, 0)
     resid = decompress_residuals_ref(res_blk, weights, nbits=nbits)
     emb = centroids.astype(jnp.float32)[safe] + resid
-    scores = jnp.einsum("bqd,bntd->bnqt", qs, emb)  # (B, n3, nq, L)
+    scores = jnp.einsum(  # (B, n3, nq, L) — f32 on every backend
+        "bqd,bntd->bnqt", qs, emb, precision=jax.lax.Precision.HIGHEST
+    )
     scores = jnp.where(tok_valid[:, :, None, :], scores, NEG)
     per_q = scores.max(axis=-1)  # (B, n3, nq)
     per_q = per_q * q_masks[:, None, :]

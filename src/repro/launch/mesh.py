@@ -19,16 +19,18 @@ import os
 
 import jax
 
+from repro.compat import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1-device mesh with the standard axis names (tests / smoke runs)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 #: set by init_distributed so repeat calls (several retriever loads in one
@@ -91,7 +93,7 @@ def make_multihost_mesh(*, axis: str = "model"):
     same ``merge_topk(axis_name=...)`` they use locally — the cross-host
     all-gather is XLA's, not ours.
     """
-    return jax.make_mesh((len(jax.devices()),), (axis,))
+    return make_mesh((len(jax.devices()),), (axis,))
 
 
 def num_chips(mesh) -> int:

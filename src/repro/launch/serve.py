@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import retrieval
+from repro import compile_cache, retrieval
 from repro.core import index as index_mod
 from repro.data import synthetic as syn
 
@@ -56,6 +56,7 @@ def main():
     ap.add_argument("--sweep-t-cs", action="store_true",
                     help="sweep the pruning threshold without recompiling")
     args = ap.parse_args()
+    compile_cache.configure()
     backend = "plaid-pallas" if args.pallas else args.backend
 
     print(f"building corpus: {args.docs} docs ...")

@@ -167,16 +167,12 @@ class Tracer:
     def device_trace(self, logdir: str):
         """Sampled device capture via ``jax.profiler.trace`` alongside the
         host spans (one ``device_trace`` span brackets the capture).  A
-        missing/failing profiler degrades to host-span-only — callers never
-        branch on platform."""
-        with self.span("device_trace", logdir=logdir):
-            try:
-                import jax.profiler
+        profiler that fails raises: a capture that silently recorded nothing
+        would read as an idle device."""
+        import jax.profiler
 
-                cm = jax.profiler.trace(logdir)
-            except Exception:
-                cm = contextlib.nullcontext()
-            with cm:
+        with self.span("device_trace", logdir=logdir):
+            with jax.profiler.trace(logdir):
                 yield
 
 

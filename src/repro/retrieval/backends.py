@@ -14,7 +14,7 @@
                       ``SearchParams(tiered=True)`` routes the plaid
                       family here automatically.
 ``plaid-tiered-pallas``  Tiered with the Pallas stage kernels (the fused
-                      megakernel runs over the compacted slice arrays).
+                      tail runs over the compacted slice arrays).
 ====================  =====================================================
 
 The mutable-corpus backends (``"live"`` / ``"live-pallas"`` /
@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import make_mesh
 from repro.core import engine_sharded
 from repro.core import indexer
 from repro.core import pipeline as pipeline_mod
@@ -343,8 +344,7 @@ class VanillaRetriever:
 # Document-sharded PLAID
 # --------------------------------------------------------------------------
 def _default_mesh():
-    devices = jax.devices()
-    return jax.make_mesh((len(devices),), ("data",))
+    return make_mesh((len(jax.devices()),), ("data",))
 
 
 @registry.register("plaid-sharded")
@@ -404,13 +404,17 @@ class ShardedRetriever:
     @classmethod
     def from_index(cls, index, cfg: RetrieverConfig):
         n_shards = cfg.n_shards or len(jax.devices())
-        idx_dict, meta, per = engine_sharded.shard_index(index, n_shards)
+        mesh = _default_mesh() if n_shards == len(jax.devices()) else None
+        idx_dict, meta, per = engine_sharded.shard_index(
+            index, n_shards, mesh=mesh
+        )
         return cls(
             idx_dict,
             meta,
             docs_per_shard=per,
             n_shards=n_shards,
             params=cfg.params,
+            mesh=mesh,
         )
 
     @classmethod
@@ -651,7 +655,7 @@ class TieredRetriever:
 
 @registry.register("plaid-tiered-pallas")
 class TieredPallasRetriever(TieredRetriever):
-    """Tiered PLAID through the Pallas kernels — the fused megakernel's
-    scalar-prefetched CSR windows run over the compacted slice arrays."""
+    """Tiered PLAID through the Pallas kernels — the fused tail's CSR
+    window gather runs over the compacted slice arrays."""
 
     impl = "pallas"

@@ -55,9 +55,9 @@ class SearchParams:
     #: (the index's weight-only-quantized centroid table).  f32
     #: accumulation in every mode; stage 4 rescores exactly.
     stage1_dtype: str = "float32"
-    #: Run the stage 3-5 tail through the fused gather->decompress->maxsim
-    #: megakernel (rank-identical to the materialized path, which survives
-    #: as the oracle).
+    #: Address the stage 3-5 tail by pid: gather the finalists' CSR windows
+    #: and score them with the stage-4 kernel (rank-identical to the path
+    #: fed by stage 2's blocks, which survives as the oracle).
     fused: bool = False
     #: Beyond-HBM storage mode: token payloads (packed residuals) stay
     #: host-resident (mmap) and only the finalists' CSR slices cross to the

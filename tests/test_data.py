@@ -44,6 +44,29 @@ def test_corpus_unit_norm_and_clustered():
     assert np.mean(same) > np.mean(diff)
 
 
+def test_corpus_stream_chunks_are_exact_and_reproducible():
+    cs = syn.CorpusStream(500, dim=16, n_concepts=256, chunk_tokens=2048,
+                          seed=3)
+    assert cs.n_docs >= 500
+    assert cs.n_tokens == len(cs.chunk_lens) * 2048
+    chunks = list(cs.chunks())
+    assert len(chunks) == len(cs.chunk_lens)
+    for payload, lens in chunks:
+        assert lens.sum() == 2048 and lens.max() <= 128
+        assert payload.shape == (2049,) and payload[:-1].max() < 256
+    # chunk c is a pure function of (seed, c)
+    again = syn.CorpusStream(500, dim=16, n_concepts=256, chunk_tokens=2048,
+                             seed=3)
+    np.testing.assert_array_equal(again.payload(1), chunks[1][0])
+    docs = cs.docs(1)
+    assert [len(d) for d in docs] == list(cs.chunk_lens[1])
+    np.testing.assert_allclose(
+        np.linalg.norm(np.concatenate(docs), axis=-1), 1.0, rtol=1e-5
+    )
+    lens = np.concatenate(cs.chunk_lens)
+    assert 40 < lens.mean() < 80  # heavy-tailed around MS MARCO's 68
+
+
 def test_queries_reference_their_gold_doc():
     docs, _ = syn.embedding_corpus(30, dim=16, seed=1)
     qs, gold = syn.queries_from_docs(docs, 10, q_len=4)

@@ -51,8 +51,9 @@ def test_sharded_matmul_per_device_flops_and_allreduce():
         w2 = jax.ShapeDtypeStruct((128,256), jnp.float32, sharding=NamedSharding(mesh, P("model", None)))
         x2 = jax.ShapeDtypeStruct((64,128), jnp.float32, sharding=NamedSharding(mesh, P("data", "model")))
         def f(x, w):
-            y = x @ w
-            return jax.lax.with_sharding_constraint(y, NamedSharding(mesh, P("data", None)))
+            # explicit mesh axes: a dot over sharded contracting dims names
+            # its output sharding (the psum the compiler must insert)
+            return jnp.dot(x, w, out_sharding=NamedSharding(mesh, P("data", None)))
         hlo2 = jax.jit(f).lower(x2, w2).compile().as_text()
         mc2 = H.analyze(hlo2)
         assert mc2.coll_bytes > 0, mc2.coll_by_kind

@@ -233,9 +233,13 @@ def test_dispatch_cache_pins_env_at_first_resolution(
 # pack <-> unpack round trip, shared between codec and kernels
 # --------------------------------------------------------------------------
 def test_unpack_shared_single_source():
-    """The fused megakernel uses the decompress kernel's _unpack — the SAME
-    function object, so bit semantics cannot drift between the two."""
-    assert kfs._unpack is kdec._unpack
+    """The fused tail scores through the decompress module's stage-4 kernel
+    — the SAME function object, so bit semantics cannot drift between the
+    two."""
+    assert (
+        kfs.decompress_and_score_batched_pallas
+        is kdec.decompress_and_score_batched_pallas
+    )
 
 
 def _roundtrip(indices, nbits):

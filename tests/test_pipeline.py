@@ -159,9 +159,10 @@ def test_facade_search_batch_diagnostics(small_index):
 def test_neg_sentinel_single_source():
     """Kernel and reference sentinels agree — and are the same constant.
 
-    ``kernels.ref`` and ``kernels.fused_score`` are pinned too: a locally
-    redefined sentinel would silently reorder equal-score ties between the
-    fused / unfused / ref paths without failing any rank test."""
+    ``kernels.ref`` is pinned too, and ``kernels.fused_score`` scores through
+    ``kernels.decompress``: a locally redefined sentinel would silently
+    reorder equal-score ties between the fused / unfused / ref paths without
+    failing any rank test."""
     from repro.kernels import fused_score as kfs
     from repro.kernels import ref as kref
 
@@ -171,7 +172,7 @@ def test_neg_sentinel_single_source():
     assert plaid.NEG == constants.NEG
     assert pipeline.NEG == constants.NEG
     assert kref.NEG is constants.NEG
-    assert kfs.NEG is constants.NEG
+    assert kfs.decompress_and_score_batched_pallas.__module__ == kdec.__name__
 
 
 def test_candidate_cap_single_source_of_truth():

@@ -494,7 +494,7 @@ def test_replica_pool_routes_to_least_outstanding():
         stubs, batch_size=1, max_wait_ms=0.0, cache_size=None
     )
     try:
-        pool.submit(_stub_query(0.0))
+        f0 = pool.submit(_stub_query(0.0))
         busy = [s for s in pool.servers if s.outstanding][0]
         _wait(
             lambda: any(r.entered.is_set() for r in stubs),
@@ -506,6 +506,8 @@ def test_replica_pool_routes_to_least_outstanding():
         for s in stubs:
             s.gate.set()
         assert f.get(timeout=10).pids[0] == 1
+        # a replica reports stats only once its request completed
+        assert f0.get(timeout=10).pids[0] == 0
         st = pool.stats()
         assert st["n_replicas"] == 2 and st["submitted"] == 2
         assert [p["completed"] for p in st["replicas"]] == [1, 1]
