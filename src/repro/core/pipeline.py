@@ -30,6 +30,11 @@ out of the jit cache key — only the per-call traced value matters.
 The old vmap-of-``_search`` path is no longer an engine entry point: the
 numerical oracle the pipeline is validated against is a plain
 ``jax.vmap(_search)`` defined locally in ``tests/test_pipeline.py``.
+
+Each stage runs under a flat ``jax.named_scope`` (``STAGES``), which names
+it in the compiled program's ``op_name`` metadata and changes nothing
+else.  A device trace carries each operation's ``op_name``, so a reader
+sums the operations' time by stage.
 """
 from __future__ import annotations
 
@@ -49,6 +54,17 @@ from repro.obs.funnel import FunnelStats
 #: order is needed (pool construction): real pids < num_passages, so the max
 #: int32 can never collide and sorts after every real pid.
 _PAD_KEY = jnp.iinfo(jnp.int32).max
+
+#: The pipeline's stage scopes, in the order the stages run: stage 1's
+#: ``C·Qᵀ`` and per-token probe top-k; the IVF walk, candidate union and
+#: cap; stage 2's shared CSR gather of the candidates' codes; stage 2's
+#: pruned interaction and top-``ndocs``; stage 3; stage 4 and the final
+#: top-k.
+STAGES = (
+    "plaid.s1", "plaid.cand", "plaid.s2.gather", "plaid.s2.score",
+    "plaid.s3", "plaid.s4",
+)
+
 
 _N_TRACES = 0
 
@@ -137,45 +153,49 @@ def candidate_generation_batched(
     keyed on the ``nprobe`` bucket.
     """
     B = s_cq.shape[0]
-    _, cids = jax.lax.top_k(jnp.swapaxes(s_cq, 1, 2), nprobe)  # (B, nq, np)
-    cids = cids.reshape(B, -1)  # (B, nq*nprobe)
-    starts = index.ivf_offsets[cids]
-    lens = index.ivf_lens[cids]
-    if nprobe_t is not None:
-        # probe rank of each flattened (token, probe) slot; masked probes
-        # get a zero-length IVF window -> contribute no pids at all
-        nq = s_cq.shape[2]
-        rank = jnp.tile(jnp.arange(nprobe, dtype=jnp.int32), nq)
-        lens = jnp.where(rank[None, :] < nprobe_t, lens, 0)
-    pos = jnp.arange(index.ivf_list_cap, dtype=jnp.int32)
-    idx = starts[..., None] + pos[None, None, :]
-    valid = pos[None, None, :] < lens[..., None]
-    idx = jnp.where(valid, idx, 0)
-    # pads are ``num_passages`` so they sort PAST every real pid through
-    # the unique truncation (same reasoning as ``plaid.candidate_generation``
-    # — a -1 pad sorts first and evicts the highest pid at a full cap)
-    n = index.num_passages
-    pids = jnp.where(valid, index.ivf_pids[idx], n)  # (B, nq*np, cap)
-    dead_pids = None
-    if alive is not None:
-        real = pids < n
-        safe = jnp.where(real, pids, 0)
-        dead = real & ~alive[safe]
-        dead_pids = jnp.where(dead, safe, n)  # raw pid where tombstoned
-        pids = jnp.where(real & alive[safe], pids, n)
-    uniq = jax.vmap(
-        functools.partial(jnp.unique, size=candidate_cap, fill_value=n)
-    )
-    candidates = uniq(pids.reshape(B, -1))
-    candidates = jnp.where(candidates < n, candidates, -1)
-    if not with_stats:
-        return candidates
-    if dead_pids is None:
-        alive_dropped = jnp.zeros(B, jnp.int32)
-    else:
-        uniq_dead = uniq(dead_pids.reshape(B, -1))
-        alive_dropped = (uniq_dead < n).sum(axis=1).astype(jnp.int32)
-    return candidates, alive_dropped
+    with jax.named_scope("plaid.s1"):
+        # (B, nq, np)
+        _, cids = jax.lax.top_k(jnp.swapaxes(s_cq, 1, 2), nprobe)
+    with jax.named_scope("plaid.cand"):
+        cids = cids.reshape(B, -1)  # (B, nq*nprobe)
+        starts = index.ivf_offsets[cids]
+        lens = index.ivf_lens[cids]
+        if nprobe_t is not None:
+            # probe rank of each flattened (token, probe) slot; masked probes
+            # get a zero-length IVF window -> contribute no pids at all
+            nq = s_cq.shape[2]
+            rank = jnp.tile(jnp.arange(nprobe, dtype=jnp.int32), nq)
+            lens = jnp.where(rank[None, :] < nprobe_t, lens, 0)
+        pos = jnp.arange(index.ivf_list_cap, dtype=jnp.int32)
+        idx = starts[..., None] + pos[None, None, :]
+        valid = pos[None, None, :] < lens[..., None]
+        idx = jnp.where(valid, idx, 0)
+        # pads are ``num_passages`` so they sort PAST every real pid through
+        # the unique truncation (same reasoning as
+        # ``plaid.candidate_generation`` — a -1 pad sorts first and evicts
+        # the highest pid at a full cap)
+        n = index.num_passages
+        pids = jnp.where(valid, index.ivf_pids[idx], n)  # (B, nq*np, cap)
+        dead_pids = None
+        if alive is not None:
+            real = pids < n
+            safe = jnp.where(real, pids, 0)
+            dead = real & ~alive[safe]
+            dead_pids = jnp.where(dead, safe, n)  # raw pid where tombstoned
+            pids = jnp.where(real & alive[safe], pids, n)
+        uniq = jax.vmap(
+            functools.partial(jnp.unique, size=candidate_cap, fill_value=n)
+        )
+        candidates = uniq(pids.reshape(B, -1))
+        candidates = jnp.where(candidates < n, candidates, -1)
+        if not with_stats:
+            return candidates
+        if dead_pids is None:
+            alive_dropped = jnp.zeros(B, jnp.int32)
+        else:
+            uniq_dead = uniq(dead_pids.reshape(B, -1))
+            alive_dropped = (uniq_dead < n).sum(axis=1).astype(jnp.int32)
+        return candidates, alive_dropped
 
 
 # --------------------------------------------------------------------------
@@ -315,78 +335,93 @@ def select_finalists_impl(
         interaction = centroid_interaction_batched
 
     # ---- Stage 1: one batched C.Q^T + per-lane candidate generation
-    s_cq = stage1_scores_batched(
-        index, qs, p.score_dtype, p.stage1_dtype
-    )  # (B, K, nq)
+    with jax.named_scope("plaid.s1"):
+        s_cq = stage1_scores_batched(
+            index, qs, p.score_dtype, p.stage1_dtype
+        )  # (B, K, nq)
     cand_out = candidate_generation_batched(
         index, s_cq, p.nprobe, p.candidate_cap, alive, with_stats=funnel,
         nprobe_t=nprobe_t,
     )  # (B, cap); tombstoned passages never reach stage 2
     if funnel:
         candidates, alive_dropped = cand_out
-        # distinct centroids the top-nprobe probe touched: recomputes the
-        # (tiny) stage-1 top_k, which XLA CSEs with candidate generation's
-        _, cids_f = jax.lax.top_k(jnp.swapaxes(s_cq, 1, 2), p.nprobe)
-        if nprobe_t is not None:
-            # probes past the traced cap collapse onto each token's top-1
-            # centroid so the distinct count matches a static nprobe_t run
-            rank_f = jnp.arange(p.nprobe, dtype=jnp.int32)[None, None, :]
-            cids_f = jnp.where(rank_f < nprobe_t, cids_f, cids_f[..., :1])
-        cids_sorted = jnp.sort(cids_f.reshape(B, -1), axis=1)
-        probed_centroids = (
-            1 + (cids_sorted[:, 1:] != cids_sorted[:, :-1]).sum(axis=1)
-        ).astype(jnp.int32)
+        with jax.named_scope("plaid.cand"):
+            # distinct centroids the top-nprobe probe touched: recomputes the
+            # (tiny) stage-1 top_k, which XLA CSEs with candidate generation's
+            _, cids_f = jax.lax.top_k(jnp.swapaxes(s_cq, 1, 2), p.nprobe)
+            if nprobe_t is not None:
+                # probes past the traced cap collapse onto each token's top-1
+                # centroid so the distinct count matches a static nprobe_t run
+                rank_f = jnp.arange(p.nprobe, dtype=jnp.int32)[None, None, :]
+                cids_f = jnp.where(rank_f < nprobe_t, cids_f, cids_f[..., :1])
+            cids_sorted = jnp.sort(cids_f.reshape(B, -1), axis=1)
+            probed_centroids = (
+                1 + (cids_sorted[:, 1:] != cids_sorted[:, :-1]).sum(axis=1)
+            ).astype(jnp.int32)
     else:
         candidates = cand_out
 
     # ---- Stage 2: pruned centroid interaction over the shared gather
-    # t_cs may be a scalar (one threshold for the batch) or a per-lane (B,)
-    # vector (the serving tier's per-request latency/quality knob); either
-    # way it is traced, so value changes reuse the compiled program.
-    t_arr = jnp.asarray(t_cs)
-    t_bcast = t_arr if t_arr.ndim == 0 else t_arr[:, None]  # vs (B, K) max
-    keep = scoring.prune_mask(s_cq, t_bcast)  # (B, K)
-    codes_blk, tok_valid = gather_candidate_tokens_shared(index, candidates)
-    approx2 = interaction(s_cq, codes_blk, q_masks, keep)  # (B, cap)
-    approx2 = jnp.where(candidates >= 0, approx2, NEG)
-    n2 = min(p.ndocs, p.candidate_cap)
-    _, idx2 = jax.lax.top_k(approx2, n2)  # (B, n2)
+    with jax.named_scope("plaid.s2.gather"):
+        codes_blk, tok_valid = gather_candidate_tokens_shared(
+            index, candidates
+        )
+    with jax.named_scope("plaid.s2.score"):
+        # t_cs may be a scalar (one threshold for the batch) or a per-lane
+        # (B,) vector (the serving tier's per-request latency/quality knob);
+        # either way it is traced, so value changes reuse the compiled
+        # program.
+        t_arr = jnp.asarray(t_cs)
+        t_bcast = t_arr if t_arr.ndim == 0 else t_arr[:, None]  # vs (B, K)
+        keep = scoring.prune_mask(s_cq, t_bcast)  # (B, K)
+        approx2 = interaction(s_cq, codes_blk, q_masks, keep)  # (B, cap)
+        approx2 = jnp.where(candidates >= 0, approx2, NEG)
+        n2 = min(p.ndocs, p.candidate_cap)
+        _, idx2 = jax.lax.top_k(approx2, n2)  # (B, n2)
 
     # ---- Stage 3: full centroid interaction on the survivors
-    codes3 = jnp.take_along_axis(codes_blk, idx2[..., None], axis=1)
-    cand2 = jnp.take_along_axis(candidates, idx2, axis=1)
-    if ndocs_t is not None:
-        # Traced stage-2 cap: approx2's real entries are >= 0 and its pads
-        # are NEG, so top_k's prefix stability means positions < n2_t of
-        # idx2 are EXACTLY what a static ndocs=ndocs_t program selects;
-        # masking the tail to -1 makes the survivor set identical.
-        nd_t = jnp.minimum(
-            jnp.asarray(ndocs_t, jnp.int32), jnp.int32(p.candidate_cap)
-        )
-        rank2 = jnp.arange(n2, dtype=jnp.int32)[None, :]
-        cand2 = jnp.where(rank2 < nd_t, cand2, -1)
-    approx3 = interaction(s_cq, codes3, q_masks, None)
-    approx3 = jnp.where(cand2 >= 0, approx3, NEG)
-    n3 = min(max(p.ndocs // 4, p.k), n2)
-    _, idx3 = jax.lax.top_k(approx3, n3)  # (B, n3)
-    final_pids = jnp.take_along_axis(cand2, idx3, axis=1)  # (B, n3)
-    if ndocs_t is not None:
-        # stage-3 keeps max(ndocs // 4, k) of its n2 survivors — apply the
-        # same rule at the traced cap (n3 >= n3_t always, so the static
-        # top_k above already ordered the prefix identically)
-        n3_t = jnp.minimum(
-            jnp.maximum(jnp.asarray(ndocs_t, jnp.int32) // 4, jnp.int32(p.k)),
-            nd_t,
-        )
-        rank3 = jnp.arange(n3, dtype=jnp.int32)[None, :]
-        final_pids = jnp.where(rank3 < n3_t, final_pids, -1)
+    with jax.named_scope("plaid.s3"):
+        codes3 = jnp.take_along_axis(codes_blk, idx2[..., None], axis=1)
+        cand2 = jnp.take_along_axis(candidates, idx2, axis=1)
+        if ndocs_t is not None:
+            # Traced stage-2 cap: approx2's real entries are >= 0 and its
+            # pads are NEG, so top_k's prefix stability means positions
+            # < n2_t of idx2 are EXACTLY what a static ndocs=ndocs_t program
+            # selects; masking the tail to -1 makes the survivor set
+            # identical.
+            nd_t = jnp.minimum(
+                jnp.asarray(ndocs_t, jnp.int32), jnp.int32(p.candidate_cap)
+            )
+            rank2 = jnp.arange(n2, dtype=jnp.int32)[None, :]
+            cand2 = jnp.where(rank2 < nd_t, cand2, -1)
+        approx3 = interaction(s_cq, codes3, q_masks, None)
+        approx3 = jnp.where(cand2 >= 0, approx3, NEG)
+        n3 = min(max(p.ndocs // 4, p.k), n2)
+        _, idx3 = jax.lax.top_k(approx3, n3)  # (B, n3)
+        final_pids = jnp.take_along_axis(cand2, idx3, axis=1)  # (B, n3)
+        if ndocs_t is not None:
+            # stage-3 keeps max(ndocs // 4, k) of its n2 survivors — apply
+            # the same rule at the traced cap (n3 >= n3_t always, so the
+            # static top_k above already ordered the prefix identically)
+            n3_t = jnp.minimum(
+                jnp.maximum(
+                    jnp.asarray(ndocs_t, jnp.int32) // 4, jnp.int32(p.k)
+                ),
+                nd_t,
+            )
+            rank3 = jnp.arange(n3, dtype=jnp.int32)[None, :]
+            final_pids = jnp.where(rank3 < n3_t, final_pids, -1)
 
-    if keep_blocks:
-        codes4 = jnp.take_along_axis(codes3, idx3[..., None], axis=1)
-        tok_valid3 = jnp.take_along_axis(tok_valid, idx2[..., None], axis=1)
-        tok_valid4 = jnp.take_along_axis(tok_valid3, idx3[..., None], axis=1)
-    else:
-        codes4 = tok_valid4 = None
+        if keep_blocks:
+            codes4 = jnp.take_along_axis(codes3, idx3[..., None], axis=1)
+            tok_valid3 = jnp.take_along_axis(
+                tok_valid, idx2[..., None], axis=1
+            )
+            tok_valid4 = jnp.take_along_axis(
+                tok_valid3, idx3[..., None], axis=1
+            )
+        else:
+            codes4 = tok_valid4 = None
 
     extras = []
     if diag:
@@ -586,17 +621,18 @@ def run_pipeline_impl(
         nprobe_t=nprobe_t,
         ndocs_t=ndocs_t,
     )
-    exact = exact_stage4_impl(
-        index,
-        qs,
-        q_masks,
-        final_pids,
-        codes4,
-        tok_valid4,
-        params=params,
-        interpret=interpret,
-    )
-    top_scores, top_pids = finalize_topk(exact, final_pids, params.k)
+    with jax.named_scope("plaid.s4"):
+        exact = exact_stage4_impl(
+            index,
+            qs,
+            q_masks,
+            final_pids,
+            codes4,
+            tok_valid4,
+            params=params,
+            interpret=interpret,
+        )
+        top_scores, top_pids = finalize_topk(exact, final_pids, params.k)
     if extras:
         return (top_scores, top_pids, *extras)
     return top_scores, top_pids

@@ -7,18 +7,18 @@ Three pillars (see ISSUE 8 / README "Observability"):
   traced reductions inside ``core.pipeline`` and merged across every
   partitioned execution layer.
 * :mod:`repro.obs.trace` — ring-buffered span :class:`Tracer` with
-  Chrome trace-event JSON export (Perfetto-loadable) and a
+  Chrome trace-event JSON export (Perfetto-loadable), span ids and
+  parents, profiler annotations on the device trace's clock, and a
   ``jax.profiler.trace`` wrapper for device captures.
-* :mod:`repro.obs.metrics` — counters / gauges / log-bucket histograms /
-  latency windows behind a :class:`MetricsRegistry` with JSON-snapshot
-  and Prometheus-text exporters.
+* :mod:`repro.obs.metrics` — counters / gauges / latency windows behind a
+  :class:`MetricsRegistry` with JSON-snapshot and Prometheus-text
+  exporters.
 """
 from repro.obs.funnel import FunnelStats
 from repro.obs.metrics import (
     Counter,
     Counters,
     Gauge,
-    Histogram,
     LatencyWindow,
     MetricsRegistry,
     get_registry,
@@ -30,7 +30,6 @@ __all__ = [
     "Counter",
     "Counters",
     "Gauge",
-    "Histogram",
     "LatencyWindow",
     "MetricsRegistry",
     "get_registry",

@@ -1,4 +1,4 @@
-"""Process-wide metrics: counters, gauges, log-bucket histograms, windows.
+"""Process-wide metrics: counters, gauges, latency windows.
 
 The serving tier grew its own ad-hoc stats (``serving/stats.py``: one
 ``LatencyWindow`` ring + a ``Counters`` bag) and everything else in the repo
@@ -10,8 +10,6 @@ module generalizes that into one substrate:
   silently created typo'd counters that no dashboard would ever read).
 * :class:`Gauge` — last-write-wins instantaneous value (queue depth,
   outstanding work, cache hit rate).
-* :class:`Histogram` — fixed log-spaced buckets (base-2 by default): O(1)
-  observe, constant memory, Prometheus-compatible cumulative export.
 * :class:`LatencyWindow` — the exact-percentile ring buffer, moved here
   from ``serving.stats`` (which remains a compatibility shim).  ``extend``
   now takes the lock ONCE per batch, not once per element.
@@ -26,7 +24,6 @@ standalone.
 """
 from __future__ import annotations
 
-import bisect
 import threading
 
 import numpy as np
@@ -136,57 +133,6 @@ class Gauge:
             return self._v
 
 
-class Histogram:
-    """Fixed log-spaced buckets: O(1) observe, constant memory.
-
-    Bucket upper bounds are ``start * factor**i`` for ``i in range(n)``
-    plus the implicit +Inf overflow bucket — the classic Prometheus
-    exponential layout.  Defaults cover 0.1ms .. ~100s in base-2 steps
-    when observations are seconds.
-    """
-
-    def __init__(
-        self,
-        name: str = "",
-        *,
-        start: float = 1e-4,
-        factor: float = 2.0,
-        n_buckets: int = 20,
-    ):
-        if start <= 0 or factor <= 1 or n_buckets < 1:
-            raise ValueError(
-                f"bad histogram layout: start={start} factor={factor} "
-                f"n_buckets={n_buckets}"
-            )
-        self.name = name
-        self.bounds = [start * factor**i for i in range(n_buckets)]
-        self._lock = threading.Lock()
-        self._counts = [0] * (n_buckets + 1)  # + overflow
-        self._sum = 0.0
-        self._n = 0
-
-    def observe(self, v: float) -> None:
-        i = bisect.bisect_left(self.bounds, float(v))
-        with self._lock:
-            self._counts[i] += 1
-            self._sum += v
-            self._n += 1
-
-    @property
-    def count(self) -> int:
-        with self._lock:
-            return self._n
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return dict(
-                count=self._n,
-                sum=self._sum,
-                bounds=list(self.bounds),
-                buckets=list(self._counts),
-            )
-
-
 class Counters:
     """A thread-safe named-counter bag — STRICT by default.
 
@@ -227,7 +173,7 @@ class Counters:
 class MetricsRegistry:
     """Named instruments + snapshot/Prometheus exporters.
 
-    ``counter``/``gauge``/``histogram``/``window`` are get-or-create:
+    ``counter``/``gauge``/``window`` are get-or-create:
     repeated calls with one name return the same instrument (asking for an
     existing name as a different kind raises).
     """
@@ -237,11 +183,11 @@ class MetricsRegistry:
         self._lock = threading.Lock()
         self._instruments: dict[str, object] = {}
 
-    def _get(self, name: str, kind, **kw):
+    def _get(self, name: str, kind):
         with self._lock:
             inst = self._instruments.get(name)
             if inst is None:
-                inst = kind(name, **kw)
+                inst = kind(name)
                 self._instruments[name] = inst
             elif not isinstance(inst, kind):
                 raise TypeError(
@@ -255,9 +201,6 @@ class MetricsRegistry:
 
     def gauge(self, name: str) -> Gauge:
         return self._get(name, Gauge)
-
-    def histogram(self, name: str, **kw) -> Histogram:
-        return self._get(name, Histogram, **kw)
 
     def window(self, name: str, capacity: int = 2048) -> LatencyWindow:
         with self._lock:
@@ -283,8 +226,6 @@ class MetricsRegistry:
                 out[name] = dict(type="counter", value=inst.value)
             elif isinstance(inst, Gauge):
                 out[name] = dict(type="gauge", value=inst.value)
-            elif isinstance(inst, Histogram):
-                out[name] = dict(type="histogram", **inst.snapshot())
             elif isinstance(inst, LatencyWindow):
                 out[name] = dict(type="window", **inst.summary())
         return out
@@ -308,17 +249,6 @@ class MetricsRegistry:
                 lines += [f"# TYPE {m} counter", f"{m} {inst.value}"]
             elif isinstance(inst, Gauge):
                 lines += [f"# TYPE {m} gauge", f"{m} {inst.value}"]
-            elif isinstance(inst, Histogram):
-                snap = inst.snapshot()
-                lines.append(f"# TYPE {m} histogram")
-                cum = 0
-                for bound, c in zip(snap["bounds"], snap["buckets"]):
-                    cum += c
-                    lines.append(f'{m}_bucket{{le="{bound:g}"}} {cum}')
-                cum += snap["buckets"][-1]
-                lines.append(f'{m}_bucket{{le="+Inf"}} {cum}')
-                lines.append(f"{m}_sum {snap['sum']}")
-                lines.append(f"{m}_count {snap['count']}")
             elif isinstance(inst, LatencyWindow):
                 s = inst.summary()
                 lines.append(f"# TYPE {m} summary")

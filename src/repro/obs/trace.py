@@ -14,6 +14,15 @@ Perfetto (https://ui.perfetto.dev) or ``chrome://tracing`` to see the
 serving tier's queue-wait/pad/dispatch timeline exactly as the paper's
 Fig. 2 shows the pipeline's stage timeline.
 
+Each span gets a process-unique ``sid`` and the ``parent`` sid of the
+innermost span open on its thread when it began, whichever ``Tracer``
+holds that span: the server's spans and the facade's, recorded into two
+tracers, still nest.  A live span (``span``) also enters
+``jax.profiler.TraceAnnotation`` under its name and attributes, so any
+profiler capture running at the time holds it on the device trace's
+clock.  A retroactive span (``record``) has no such twin: it keeps the
+tracer's clock, and a reader places it on the trace's.
+
 Determinism hooks for tests: the wall clock is injectable (``clock=``
 takes any ``() -> float`` seconds callable), so a test can drive spans
 with a fake clock and assert exact ``ts``/``dur`` values.  The real
@@ -21,35 +30,54 @@ default is ``time.perf_counter`` (monotonic — spans never go backwards
 under NTP slews).
 
 ``device_trace`` wraps ``jax.profiler.trace`` for sampled device-side
-captures next to the host spans; it degrades to a no-op where the
-profiler is unavailable (e.g. some CPU-only wheels).
+captures; the live spans of every tracer land in the capture beside the
+device's operations.
 """
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
 
+from jax import profiler as _profiler
+
 #: Chrome trace-event "complete event" phase — one event carries ts + dur.
 _PH_COMPLETE = "X"
 #: Instant-event phase (scope "t": thread-scoped tick mark).
 _PH_INSTANT = "i"
 
+#: Span ids, unique in the process (``next`` on a count is atomic under
+#: the GIL).
+_IDS = itertools.count(1)
+#: Per thread, the sids of the live spans open on it, innermost last.
+_OPEN = threading.local()
+
+
+def _open_spans() -> list:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
 
 class Span:
-    """One recorded span: name, start (s), duration (s), thread, attrs."""
+    """One recorded span: name, start (s), duration (s), thread, attrs, its
+    id and its parent's (None at the top of its thread)."""
 
-    __slots__ = ("name", "ts", "dur", "tid", "attrs")
+    __slots__ = ("name", "ts", "dur", "tid", "attrs", "sid", "parent")
 
-    def __init__(self, name, ts, dur, tid, attrs):
+    def __init__(self, name, ts, dur, tid, attrs, sid=None, parent=None):
         self.name = name
         self.ts = ts
         self.dur = dur
         self.tid = tid
         self.attrs = attrs
+        self.sid = sid
+        self.parent = parent
 
     def __repr__(self):  # pragma: no cover - debugging aid
         return f"Span({self.name!r}, ts={self.ts:.6f}, dur={self.dur:.6f})"
@@ -70,13 +98,20 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
         """Context manager recording one complete span (exceptions still
-        record — a failed dispatch is exactly the span you want to see)."""
+        record — a failed dispatch is exactly the span you want to see),
+        annotated on the profiler's clock while it is open."""
+        stack = _open_spans()
+        parent = stack[-1] if stack else None
+        sid = next(_IDS)
+        stack.append(sid)
         t0 = self._clock()
         try:
-            yield self
+            with _profiler.TraceAnnotation(name, **attrs):
+                yield self
         finally:
             t1 = self._clock()
-            self._record(name, t0, t1 - t0, attrs)
+            stack.pop()
+            self._record(name, t0, t1 - t0, attrs, sid, parent)
 
     def instant(self, name: str, **attrs) -> None:
         """Zero-duration marker (generation bumps, shed events, ...)."""
@@ -88,8 +123,13 @@ class Tracer:
         span: the wait is only known at dispatch time, after it ended."""
         self._record(name, ts, max(dur, 0.0), attrs)
 
-    def _record(self, name, ts, dur, attrs) -> None:
-        s = Span(name, ts, dur, threading.get_ident(), attrs or None)
+    def _record(self, name, ts, dur, attrs, sid=None, parent=None) -> None:
+        if sid is None:
+            stack = _open_spans()
+            sid, parent = next(_IDS), (stack[-1] if stack else None)
+        s = Span(
+            name, ts, dur, threading.get_ident(), attrs or None, sid, parent
+        )
         with self._lock:
             self._buf.append(s)
 
@@ -133,7 +173,8 @@ class Tracer:
         """Chrome trace-event JSON object (Perfetto-loadable).
 
         ``ts``/``dur`` are microseconds per the trace-event spec; complete
-        spans use ``ph: "X"``, instants ``ph: "i"``.
+        spans use ``ph: "X"``, instants ``ph: "i"``.  ``id`` is the span's
+        sid and ``args.parent`` its parent's, where it has one.
         """
         pid = os.getpid()
         events = []
@@ -144,14 +185,18 @@ class Tracer:
                 ts=s.ts * 1e6,
                 pid=pid,
                 tid=s.tid,
+                id=s.sid,
             )
             if ev["ph"] == _PH_COMPLETE:
                 ev["dur"] = s.dur * 1e6
             else:
                 ev["s"] = "t"
                 ev["dur"] = 0.0
-            if s.attrs:
-                ev["args"] = {k: _jsonable(v) for k, v in s.attrs.items()}
+            args = {k: _jsonable(v) for k, v in (s.attrs or {}).items()}
+            if s.parent is not None:
+                args["parent"] = s.parent
+            if args:
+                ev["args"] = args
             events.append(ev)
         return dict(traceEvents=events, displayTimeUnit="ms")
 
@@ -169,10 +214,8 @@ class Tracer:
         host spans (one ``device_trace`` span brackets the capture).  A
         profiler that fails raises: a capture that silently recorded nothing
         would read as an idle device."""
-        import jax.profiler
-
         with self.span("device_trace", logdir=logdir):
-            with jax.profiler.trace(logdir):
+            with _profiler.trace(logdir):
                 yield
 
 

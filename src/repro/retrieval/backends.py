@@ -30,6 +30,7 @@ serve time never recompiles (``describe()["compile"]`` proves it).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 
@@ -43,6 +44,7 @@ from repro.core import indexer
 from repro.core import pipeline as pipeline_mod
 from repro.core import plaid as plaid_mod
 from repro.core import vanilla as vanilla_mod
+from repro.obs.trace import get_tracer
 from repro.retrieval import registry
 from repro.retrieval.types import (
     DYNAMIC_FIELDS,
@@ -110,14 +112,16 @@ def _reject_funnel(req: SearchRequest, backend: str) -> None:
 
 
 def _finish(
-    out, *, backend, k, t_cs, t0, diag_names=None, funnel=False
+    out, *, backend, k, t_cs, t0, diag_names=None, funnel=False,
+    tracer=None,
 ) -> SearchResult:
     """Block on device results and wrap them with serving metadata.
 
     Blocking is part of the facade contract: ``SearchResult.latency_ms``
     measures a completed search.  Callers that want async dispatch and
     device/host overlap (request pipelining) use the core engines, which
-    return unblocked device arrays."""
+    return unblocked device arrays.  With a ``tracer`` the wait is a
+    ``retrieval.block`` span."""
     scores, pids, *extras = out
     diagnostics = funnel_stats = None
     if diag_names is not None:
@@ -125,7 +129,9 @@ def _finish(
         diagnostics = {name: diagnostics[name] for name in diag_names}
     if funnel:
         funnel_stats = extras.pop(0)
-    jax.block_until_ready(pids)
+    block = tracer.span("retrieval.block") if tracer else None
+    with block or contextlib.nullcontext():
+        jax.block_until_ready(pids)
     latency_ms = (time.perf_counter() - t0) * 1e3
     if diagnostics is not None:
         diagnostics = {
@@ -207,22 +213,32 @@ class PlaidRetriever:
 
     def search_batch(self, qs, q_masks=None, *, t_cs=None,
                      with_diagnostics=False, with_funnel=False):
-        req = _as_request(qs, q_masks, t_cs, with_diagnostics, with_funnel)
-        t = self.params.t_cs if req.t_cs is None else req.t_cs
-        t0 = time.perf_counter()
-        out = self._engine.search_batch(
-            req.q, req.q_mask, t_cs=t, diag=req.with_diagnostics,
-            funnel=req.with_funnel,
-        )
-        return _finish(
-            out,
-            backend=self.backend_name,
-            k=self.params.k,
-            t_cs=t,
-            t0=t0,
-            diag_names=_DIAG_NAMES if req.with_diagnostics else None,
-            funnel=req.with_funnel,
-        )
+        """Spans, into the process's tracer: ``retrieval.search_batch``
+        around the call; in it ``retrieval.launch`` (request packing, the
+        engine, the program's dispatch) until the engine returns unblocked
+        arrays, then ``retrieval.block`` until they are ready."""
+        tracer = get_tracer()
+        with tracer.span("retrieval.search_batch", backend=self.backend_name):
+            with tracer.span("retrieval.launch"):
+                req = _as_request(
+                    qs, q_masks, t_cs, with_diagnostics, with_funnel
+                )
+                t = self.params.t_cs if req.t_cs is None else req.t_cs
+                t0 = time.perf_counter()
+                out = self._engine.search_batch(
+                    req.q, req.q_mask, t_cs=t, diag=req.with_diagnostics,
+                    funnel=req.with_funnel,
+                )
+            return _finish(
+                out,
+                backend=self.backend_name,
+                k=self.params.k,
+                t_cs=t,
+                t0=t0,
+                diag_names=_DIAG_NAMES if req.with_diagnostics else None,
+                funnel=req.with_funnel,
+                tracer=tracer,
+            )
 
     # ---- introspection ---------------------------------------------------
     def describe(self) -> dict:

@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import itertools
 import queue as queue_mod
 import threading
 import time
@@ -121,6 +122,7 @@ class _Pending:
     deadline: float | None  # absolute perf_counter expiry, or None
     future: ResultFuture
     cache_key: tuple | None  # None = don't cache this request
+    rid: int | None = None  # ``submit``'s id of the request (spans' ``rid``)
 
     def fail(self, exc: BaseException) -> None:
         self.future.set_exception(exc)
@@ -159,6 +161,10 @@ class BatchingServer:
             "dispatches", "retraces",
         )
         self._bucket_dispatches: dict[int, int] = {}
+        # ids that link a request's spans to its batch's: ``rid`` per
+        # admitted request, ``batch`` per dispatched batch
+        self._rids = itertools.count()
+        self._batches = itertools.count()
         self._warm: set = set()  # (bucket, generation) pairs already traced
         self._inflight = 0
         # observability: span tracer + gauge registry.  Defaults are the
@@ -316,7 +322,7 @@ class BatchingServer:
         )
         pending = _Pending(
             q=q, t_cs=t, k=kk, t0=t0, deadline=deadline,
-            future=ResultFuture(), cache_key=key,
+            future=ResultFuture(), cache_key=key, rid=next(self._rids),
         )
         self._q.put(pending, priority)  # QueueFull / ServerClosed
         self._g_queue_depth.set(len(self._q))
@@ -492,18 +498,20 @@ class BatchingServer:
         from repro.core import pipeline as pipeline_mod
 
         n = len(batch)
+        seq = next(self._batches)
         dispatch_t0 = time.perf_counter()
         for p in batch:
             # the wait is only measurable once it ends: record retroactively
             self.tracer.record(
-                "serve.queue_wait", p.t0, dispatch_t0 - p.t0
+                "serve.queue_wait", p.t0, dispatch_t0 - p.t0,
+                rid=p.rid, batch=seq,
             )
         bucket = (
             buckets_mod.bucket_batch_size(n, self.batch_size)
             if self.bucketed
             else self.batch_size
         )
-        with self.tracer.span("serve.pad", bucket=bucket, n=n):
+        with self.tracer.span("serve.pad", bucket=bucket, n=n, batch=seq):
             qs, ts = buckets_mod.pad_batch(
                 [p.q for p in batch], [p.t_cs for p in batch], bucket
             )
@@ -517,7 +525,7 @@ class BatchingServer:
             # serves every per-request t_cs combination
             kwargs["t_cs"] = jnp.asarray(ts)
         with self.tracer.span(
-            "serve.dispatch", bucket=bucket, n=n, generation=gen0
+            "serve.dispatch", bucket=bucket, n=n, generation=gen0, batch=seq
         ):
             out = self.retriever.search_batch(jnp.asarray(qs), **kwargs)
             scores, pids = out  # SearchResult iterates as (scores, pids)
@@ -540,7 +548,7 @@ class BatchingServer:
         # cache only if no mutation raced the batch: the snapshot the
         # search actually ran against is then unambiguously gen0
         gen_ok = self.cache is not None and self._generation() == gen0
-        with self.tracer.span("serve.truncate", n=n):
+        with self.tracer.span("serve.truncate", n=n, batch=seq):
             for i, p in enumerate(batch):
                 if gen_ok and p.cache_key is not None:
                     self.cache.put(p.cache_key, gen0, scores[i], pids[i])

@@ -4,7 +4,7 @@
 named-counter bag, now STRICT by default — incrementing a name the bag was
 not constructed with raises instead of silently creating an unread
 counter) live in :mod:`repro.obs.metrics` alongside the rest of the
-metrics substrate (gauges, histograms, the process-wide registry and its
+metrics substrate (gauges, the process-wide registry and its
 Prometheus/JSON exporters).  Import from ``repro.obs`` in new code; this
 module keeps the historical ``repro.serving.stats`` names working.
 """

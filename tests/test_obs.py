@@ -24,7 +24,6 @@ from repro.obs.metrics import (
     Counter,
     Counters,
     Gauge,
-    Histogram,
     LatencyWindow,
     MetricsRegistry,
 )
@@ -91,20 +90,6 @@ def test_latency_window_extend_single_lock_acquisition():
     assert lock.acquisitions == 1
 
 
-def test_histogram_log_buckets_and_overflow():
-    h = Histogram("lat", start=1e-3, factor=2.0, n_buckets=4)
-    # bounds: 1ms, 2ms, 4ms, 8ms (+Inf overflow)
-    for v in (0.0005, 0.003, 0.1):
-        h.observe(v)
-    snap = h.snapshot()
-    assert snap["count"] == 3
-    assert snap["buckets"][0] == 1  # 0.5ms <= 1ms
-    assert snap["buckets"][2] == 1  # 3ms <= 4ms
-    assert snap["buckets"][-1] == 1  # 100ms -> overflow
-    with pytest.raises(ValueError):
-        Histogram("bad", factor=1.0)
-
-
 def test_registry_get_or_create_and_kind_mismatch():
     r = MetricsRegistry()
     assert r.counter("x") is r.counter("x")
@@ -116,19 +101,15 @@ def test_registry_snapshot_and_prometheus_export():
     r = MetricsRegistry(namespace="repro")
     r.counter("reqs").inc(5)
     r.gauge("depth").set(3)
-    r.histogram("lat", start=1e-3, factor=2.0, n_buckets=3).observe(0.002)
     r.window("w").add(0.01)
     snap = r.snapshot()
     assert snap["reqs"] == dict(type="counter", value=5)
     assert snap["depth"]["value"] == 3.0
-    assert snap["lat"]["count"] == 1
     assert snap["w"]["n"] == 1
     json.dumps(snap)  # JSON-safe end to end
     text = r.to_prometheus()
     assert "# TYPE repro_reqs counter" in text
     assert "repro_reqs 5" in text
-    assert 'repro_lat_bucket{le="+Inf"} 1' in text
-    assert "repro_lat_count 1" in text
 
 
 def test_serving_stats_shim_reexports():
@@ -234,6 +215,96 @@ def test_chrome_trace_export_round_trips(tmp_path):
     for e in events:
         assert isinstance(e["ts"], float) and isinstance(e["dur"], float)
         assert isinstance(e["pid"], int) and isinstance(e["tid"], int)
+
+
+def test_span_ids_and_parents_across_tracers():
+    """Every span gets a process-unique sid; its parent is the innermost
+    span open on its thread, whichever tracer holds that span."""
+    a, b = Tracer(), Tracer()
+    with a.span("outer"):
+        with b.span("inner"):
+            a.instant("tick")
+        b.record("late", 0.0, 1.0)
+    with b.span("top"):
+        pass
+    (outer,), (inner,), (tick,) = a.spans("outer"), b.spans("inner"), a.spans("tick")
+    (late,), (top,) = b.spans("late"), b.spans("top")
+    sids = [s.sid for s in (outer, inner, tick, late, top)]
+    assert len(set(sids)) == 5 and all(isinstance(i, int) for i in sids)
+    assert outer.parent is None and top.parent is None
+    assert inner.parent == outer.sid
+    assert tick.parent == inner.sid
+    assert late.parent == outer.sid
+    # another thread starts with nothing open
+    def work():
+        with a.span("other"):
+            pass
+
+    with a.span("held"):
+        th = threading.Thread(target=work)
+        th.start()
+        th.join(timeout=30)
+    assert not th.is_alive()
+    assert a.spans("other")[0].parent is None
+
+
+def test_span_enters_profiler_annotation(monkeypatch):
+    """A live span is also a jax.profiler.TraceAnnotation under its name and
+    attributes, open strictly inside the tracer's clock readings; a
+    retroactive one is not."""
+    from repro.obs import trace as trace_mod
+
+    t = [0.0]
+    events = []
+
+    def clock():
+        t[0] += 1.0
+        events.append(("clock", t[0]))
+        return t[0]
+
+    class FakeAnnotation:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            events.append(("enter", self.name, self.kw))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+
+    monkeypatch.setattr(trace_mod._profiler, "TraceAnnotation", FakeAnnotation)
+    tr = Tracer(clock=clock)
+    with tr.span("serve.dispatch", batch=7, bucket=4):
+        events.append(("body",))
+    tr.record("serve.queue_wait", 0.5, 0.25, rid=3, batch=7)
+    assert events == [
+        ("clock", 1.0),
+        ("enter", "serve.dispatch", {"batch": 7, "bucket": 4}),
+        ("body",),
+        ("exit", "serve.dispatch"),
+        ("clock", 2.0),
+    ]
+    d, q = tr.spans()
+    assert (d.ts, d.dur, d.attrs) == (1.0, 1.0, {"batch": 7, "bucket": 4})
+    assert (q.ts, q.dur, q.attrs) == (0.5, 0.25, {"rid": 3, "batch": 7})
+
+
+def test_chrome_trace_export_carries_ids_and_parents():
+    t = [0.0]
+
+    def clock():
+        t[0] += 0.5
+        return t[0]
+
+    tr = Tracer(clock=clock)
+    with tr.span("a"):
+        with tr.span("b", n=2):
+            pass
+    events = {e["name"]: e for e in json.loads(json.dumps(tr.to_chrome_trace()))["traceEvents"]}
+    a, b = tr.spans("a")[0], tr.spans("b")[0]
+    assert events["a"]["id"] == a.sid and "args" not in events["a"]
+    assert events["b"]["id"] == b.sid
+    assert events["b"]["args"] == {"n": 2, "parent": a.sid}
 
 
 def test_tracer_summary_rollup():

@@ -224,6 +224,34 @@ def test_diagnostics_unsupported_backends_raise(built):
             r.search_batch(qs, with_diagnostics=True)
 
 
+@pytest.mark.parametrize("backend", ["plaid", "plaid-pallas"])
+def test_search_batch_spans_nest_under_the_caller(built, backend):
+    """``search_batch`` records ``retrieval.search_batch`` into the process
+    tracer as a child of the caller's open span (another tracer's), with
+    ``retrieval.launch`` then ``retrieval.block`` inside it."""
+    from repro.obs import get_tracer
+    from repro.obs.trace import Tracer
+
+    _, idx, qs, _ = built
+    r = _retriever(idx, backend)
+    r.search_batch(qs)  # compiled
+    caller = Tracer()
+    with caller.span("serve.dispatch"):
+        r.search_batch(qs)
+    (outer,) = caller.spans()
+    spans = get_tracer().spans()
+    (call,) = [s for s in spans if s.name == "retrieval.search_batch"
+               and s.parent == outer.sid]
+    assert call.attrs == {"backend": backend}
+    (launch,) = [s for s in spans if s.parent == call.sid
+                 and s.name == "retrieval.launch"]
+    (block,) = [s for s in spans if s.parent == call.sid
+                and s.name == "retrieval.block"]
+    assert outer.ts <= call.ts <= launch.ts
+    assert launch.ts + launch.dur <= block.ts
+    assert block.ts + block.dur <= call.ts + call.dur <= outer.ts + outer.dur
+
+
 def test_search_request_object(built):
     docs, idx, qs, gold = built
     r = _retriever(idx, "plaid")

@@ -334,6 +334,40 @@ def test_dispatch_exception_propagates_and_dispatcher_survives():
 # ---------------------------------------------------------------------------
 # satellite: bounded latency window
 # ---------------------------------------------------------------------------
+def test_spans_link_each_request_to_its_batch():
+    """Every answered request's ``serve.queue_wait`` names its ``rid`` and
+    exactly one ``batch``, whose ``serve.pad`` / ``serve.dispatch`` /
+    ``serve.truncate`` carry the same number and begin after the wait
+    ends."""
+    from repro.obs.trace import Tracer
+
+    stub = StubRetriever(gated=True)
+    tracer = Tracer()
+    srv = BatchingServer(
+        stub, batch_size=4, max_wait_ms=1.0, cache_size=None, tracer=tracer
+    )
+    try:
+        futs = [srv.submit(_stub_query(1.0))]
+        _wait(stub.entered.is_set, msg="first dispatch")
+        futs += [srv.submit(_stub_query(2.0 + i)) for i in range(9)]
+        stub.gate.set()
+        for f in futs:
+            f.get(timeout=30)
+    finally:
+        srv.shutdown()
+    waits = tracer.spans("serve.queue_wait")
+    assert sorted(w.attrs["rid"] for w in waits) == list(range(10))
+    assert len({w.attrs["batch"] for w in waits}) >= 3  # 1, then 4 + 4 + 1
+    for name in ("serve.pad", "serve.dispatch", "serve.truncate"):
+        by_batch = [s.attrs["batch"] for s in tracer.spans(name)]
+        assert sorted(by_batch) == sorted({w.attrs["batch"] for w in waits})
+    dispatch = {s.attrs["batch"]: s for s in tracer.spans("serve.dispatch")}
+    for w in waits:
+        d = dispatch[w.attrs["batch"]]
+        assert w.ts + w.dur <= d.ts
+        assert sum(1 for v in waits if v.attrs["batch"] == w.attrs["batch"]) == d.attrs["n"]
+
+
 def test_latency_window_bounded_and_exact():
     w = LatencyWindow(capacity=4)
     assert w.summary() == {}
