@@ -14,9 +14,11 @@ B >= 1 (B = 1 is a squeeze at the caller, not a separate code path):
 ``candidate_generation_batched``
     Per-lane top-``nprobe`` probe + IVF union, batched over B.
 ``gather_candidate_tokens_shared``
-    ONE doc-token gather for the whole batch: lanes' candidate sets are
-    deduplicated into a shared sorted pool, gathered once, and re-expanded
-    per lane — candidates common across the batch are fetched once.
+    ONE code gather for the whole batch: lanes' candidate sets are
+    deduplicated into a shared sorted pool, each pooled passage's code
+    window is fetched as two aligned rows of the packed codes, and the
+    pool is re-expanded per lane — candidates common across the batch are
+    fetched once.
 ``centroid_interaction_batched`` / ``decompress_score_batched``
     Stages 2–4 over (B, cap) candidate blocks; with ``impl="pallas"`` these
     dispatch to the batched-grid kernels (``repro.kernels.ops``).
@@ -204,14 +206,15 @@ def candidate_generation_batched(
 def gather_candidate_tokens_shared(
     index: PlaidIndex, candidates: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
-    """One doc-token gather for the whole batch's candidate union.
+    """One code gather for the whole batch's candidate union.
 
     candidates: (B, cap) per-lane sorted unique pids (-1 pad).  The lanes'
     sets are merged into one sorted pool of static size B*cap (-1 remapped
-    to ``_PAD_KEY`` so the pool stays sorted); the packed codes are gathered
-    from HBM once for the pool, then re-expanded per lane through the cheap
-    int32 position map.  Candidates shared across lanes — the common case
-    under correlated traffic — are fetched exactly once.
+    to ``_PAD_KEY`` so the pool stays sorted); each pooled passage's code
+    window is gathered from HBM once, as two aligned W-slot rows shifted
+    into place (``scoring.gather_doc_tokens``), then re-expanded per lane
+    through the cheap int32 position map.  Candidates shared across lanes
+    — the common case under correlated traffic — are fetched exactly once.
 
     Returns (codes (B, cap, L) with -1 pad, tok_valid (B, cap, L) bool),
     bitwise identical to per-lane ``scoring.gather_doc_tokens`` output.

@@ -115,15 +115,49 @@ def gather_doc_tokens(
 ) -> jax.Array:
     """Gather packed per-token payload into a (nd, doc_maxlen, ...) block.
 
-    Out-of-range gathers are clamped by jnp and overwritten with ``fill``.
+    A 1-D payload (the codes) is gathered a window at a time
+    (``_gather_windows``); a payload with per-token rows (the residuals)
+    gathers one row per token.  Slots past a passage's length, and every
+    slot of a ``-1`` pad, are overwritten with ``fill``.
     """
     safe_pid = jnp.where(pids >= 0, pids, 0)
     start = doc_offsets[safe_pid]  # (nd,)
     lens = jnp.where(pids >= 0, doc_lens[safe_pid], 0)
     pos = jnp.arange(doc_maxlen, dtype=jnp.int32)
-    tok_idx = start[:, None] + pos[None, :]
     valid = pos[None, :] < lens[:, None]
-    tok_idx = jnp.where(valid, tok_idx, 0)
-    out = values[tok_idx]
+    if values.ndim == 1:
+        out = _gather_windows(values, start, doc_maxlen)
+    else:
+        out = values[jnp.where(valid, start[:, None] + pos[None, :], 0)]
     mask_shape = valid.shape + (1,) * (out.ndim - 2)
     return jnp.where(valid.reshape(mask_shape), out, fill), valid
+
+
+def _gather_windows(values: jax.Array, start: jax.Array, n: int) -> jax.Array:
+    """(nd, n) block of ``values[start[i] : start[i] + n]`` for a 1-D payload.
+
+    A TPU gather costs per index, not per byte, so each window is fetched
+    as two aligned rows of W = 128 * ceil(n / 128) slots, which always cover
+    it: ``values`` is viewed as rows of W (its tail padded), one gather with
+    slice size (1, W) takes rows ``start // W`` and ``start // W + 1`` of
+    every window, and a barrel shift of log2(W) static-slice selects moves
+    each 2W-slot pair left by ``start % W``.  Slots past the end of
+    ``values`` read the padding.
+    """
+    W = 128 * -(-n // 128)
+    nt = values.shape[0]
+    rows = jnp.pad(values, (0, (nt // W + 2) * W - nt)).reshape(-1, W)
+    r = start // W
+    x = rows[jnp.stack([r, r + 1], axis=1)].reshape(-1, 2 * W)
+    off = start % W
+    bits = (W - 1).bit_length()
+    width = n + (1 << bits) - 1  # the widest slice the first select takes
+    if width > 2 * W:  # W not a power of two: the top bit overshoots the pair
+        x = jnp.pad(x, ((0, 0), (0, width - 2 * W)))
+    for j in reversed(range(bits)):
+        step = 1 << j
+        keep = n + step - 1  # slots the remaining, smaller shifts can reach
+        x = jnp.where(
+            (off & step)[:, None] != 0, x[:, step:step + keep], x[:, :keep]
+        )
+    return x

@@ -1,8 +1,10 @@
 """Batch-first stage pipeline: oracle equivalence, compile discipline,
-shared sentinels/caps, batched kernels, and the stage-1 single-matmul HLO
-regression guard."""
+shared sentinels/caps, batched kernels, the stage-2 code-window gather, and
+the stage-1 single-matmul and stage-2 row-gather HLO regression guards."""
 import dataclasses
 import functools
+import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +120,85 @@ def test_shared_gather_matches_per_lane_gather(small_index):
         )
         np.testing.assert_array_equal(np.asarray(codes_b[b]), np.asarray(codes_1))
         np.testing.assert_array_equal(np.asarray(valid_b[b]), np.asarray(valid_1))
+
+
+def _code_windows(case: str, doc_maxlen: int, rng):
+    """(starts, lens, pids) of code windows over a payload of ``nt`` slots
+    that probe the edges of the aligned W-slot rows the gather fetches."""
+    W = 128 * -(-doc_maxlen // 128)
+    nt = 3 * W + 37
+    edges = [0, W - 1, W, 2 * W - 1]
+    if case == "last_token":  # windows that end on the payload's last slot
+        lens = [doc_maxlen, 1, 70]
+        starts = [nt - n for n in lens]
+    elif case == "row_edges":  # each edge start, at both extreme lengths
+        starts = edges * 2
+        lens = [doc_maxlen] * 4 + [1] * 4
+    elif case == "lengths":
+        lens = list(rng.integers(1, doc_maxlen + 1, 64))
+        starts = [int(rng.integers(0, nt - n + 1)) for n in lens]
+    elif case == "pad":
+        starts, lens = edges, [doc_maxlen, 1, 5, doc_maxlen]
+        return nt, starts, lens, np.array([-1, 0, -1, 3, 2, -1], np.int32)
+    return nt, starts, lens, np.arange(len(starts), dtype=np.int32)
+
+
+def _windows_oracle(values, starts, lens, pids, doc_maxlen):
+    """Plain per-token indexing: ``values[start + pos]`` for pos < len."""
+    out = np.full((len(pids), doc_maxlen), -1, values.dtype)
+    for i, p in enumerate(pids):
+        if p >= 0:
+            for pos in range(lens[p]):
+                out[i, pos] = values[starts[p] + pos]
+    return out
+
+
+@pytest.mark.parametrize("doc_maxlen", [128, 180, 300])
+@pytest.mark.parametrize(
+    "case", ["last_token", "row_edges", "lengths", "pad", "shared_pool"]
+)
+def test_code_window_gather_matches_per_token_indexing(case, doc_maxlen):
+    """The 1-D path of ``gather_doc_tokens`` (two aligned rows and a shift
+    per window) returns what per-token indexing returns, bit for bit, with
+    -1 past each passage's length and in every slot of a ``-1`` pad;
+    ``shared_pool`` runs it under the pipeline's B = 2 pool, whose lanes
+    share candidates, over a CSR whose last passage ends on the last
+    token."""
+    rng = np.random.default_rng(doc_maxlen)
+    if case == "shared_pool":
+        lens = rng.integers(1, doc_maxlen + 1, 40).astype(np.int32)
+        lens[[3, 17, 39]] = doc_maxlen
+        starts = np.concatenate([[0], np.cumsum(lens)]).astype(np.int32)
+        nt = int(starts[-1])
+        values = rng.integers(0, 1 << 30, nt).astype(np.int32)
+        cands = np.full((2, 24), -1, np.int32)
+        cands[0, :16] = np.arange(0, 32, 2)  # lanes share pids 12..30
+        cands[1, :20] = np.sort(rng.choice(np.arange(12, 40), 20, False))
+        index = types.SimpleNamespace(
+            codes=jnp.asarray(values), doc_offsets=jnp.asarray(starts),
+            doc_lens=jnp.asarray(lens), doc_maxlen=doc_maxlen,
+        )
+        codes, valid = pipeline.gather_candidate_tokens_shared(
+            index, jnp.asarray(cands)
+        )
+        for b in range(2):
+            want = _windows_oracle(values, starts, lens, cands[b], doc_maxlen)
+            np.testing.assert_array_equal(np.asarray(codes[b]), want)
+            np.testing.assert_array_equal(np.asarray(valid[b]), want != -1)
+        return
+    nt, starts, lens, pids = _code_windows(case, doc_maxlen, rng)
+    values = rng.integers(0, 1 << 30, nt).astype(np.int32)
+    got, valid = scoring.gather_doc_tokens(
+        jnp.asarray(values),
+        jnp.asarray(np.append(starts, nt).astype(np.int32)),
+        jnp.asarray(np.asarray(lens, np.int32)),
+        jnp.asarray(pids),
+        doc_maxlen,
+        fill=-1,
+    )
+    want = _windows_oracle(values, starts, lens, pids, doc_maxlen)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(valid), want != -1)
 
 
 def test_diag_batched_matches_single_query(small_index):
@@ -276,3 +357,43 @@ def test_stage1_lowers_to_single_batchwide_matmul():
             assert not (n == K * nq and K in dims), ins.raw
     assert len(stage1) == 1, [s[1].raw for s in stage1]
     assert stage1[0][2] == 1.0, "stage-1 dot must not sit inside a loop"
+
+
+# --------------------------------------------------------------------------
+# Acceptance: stage 2 gathers the codes by W-slot rows, not by token
+# --------------------------------------------------------------------------
+def test_stage2_code_gather_lowers_to_row_gather():
+    """Regression guard: the stage-2 CSR gather of ``index.codes`` fetches
+    each candidate's window as two rows of W = 128 * ceil(L / 128) slots
+    (a gather with slice size (1, W)); no gather under ``plaid.s2.gather``
+    fetches the codes a slot at a time (slice size 1, one index per slot
+    of the (pool, L) block)."""
+    docs, _ = syn.embedding_corpus(
+        80, dim=16, min_len=9, max_len=14, seed=0
+    )
+    idx = index_mod.build_index(docs, num_centroids=32, nbits=2, kmeans_iters=2)
+    nq, B, cap = 5, 3, 32
+    L, W = idx.doc_maxlen, 128 * -(-idx.doc_maxlen // 128)
+    qs = jnp.asarray(
+        np.random.default_rng(0).normal(size=(B, nq, 16)).astype(np.float32)
+    )
+    params = plaid.SearchParams(k=4, nprobe=2, ndocs=16, candidate_cap=cap)
+    hlo = pipeline.run_pipeline_jit.lower(
+        idx, qs, jnp.ones((B, nq), jnp.float32), jnp.float32(0.4),
+        params=params,
+    ).compile().as_text()
+    gathers = [
+        ins
+        for comp in hlo_analysis.parse_module(hlo).values()
+        for ins in comp.instrs
+        if ins.op == "gather" and "/plaid.s2.gather/" in ins.raw
+    ]
+    sizes = [
+        (hlo_analysis._shape_dims(g.rtype),
+         re.search(r"slice_sizes=\{([\d,]*)\}", g.raw).group(1))
+        for g in gathers
+    ]
+    rows = [d for d, s in sizes if s == f"1,{W}"]
+    assert [int(np.prod(d)) for d in rows] == [B * cap * 2 * W], sizes
+    per_slot = [d for d, s in sizes if s == "1" and np.prod(d) >= B * cap * L]
+    assert not per_slot, sizes
