@@ -48,6 +48,16 @@ def _answers(r, qs, batch: int):
     return np.concatenate(out_s), np.concatenate(out_p)
 
 
+def _with_params(r, params):
+    """The same retriever over the same device arrays at other settings."""
+    if hasattr(r, "index"):
+        return type(r)(r.index, params)
+    return type(r)(  # plaid-sharded holds its shards' arrays, not an index
+        r._idx_dict, r._meta, docs_per_shard=r.docs_per_shard, n_shards=r.n_shards,
+        params=params, mesh=r.mesh,
+    )
+
+
 def _drawn(cell, corpus, seed: int):
     """The queries a run draws, and the sample of them whose answers it
     compares with the plain four stages."""
@@ -78,12 +88,13 @@ def main(argv=None) -> None:
     cell = spec_mod.load_cell(ROOT / "BENCHMARK.json", args.workload)
     _device_check(cell.workload["chips"], args.rehearse)
     cfg, t = cell.config, cell.traffic
+    shards = spec_mod.shards(cfg)
     k = cfg["search"]["k"]
     batch = t["batch"] if "batch" in t else t["batch_size"]  # a shape the window runs
     params = _params(cfg)
     corpus = corpus_mod.Corpus(corpus_mod.CorpusSpec.from_config(cfg))
-    r, _ = corpus_mod.load_or_build(corpus, cfg["backend"], params, log=log)
-    bf16 = type(r)(r.index, r.params.replace(score_dtype="bfloat16"))
+    r, _ = corpus_mod.load_or_build(corpus, cfg["backend"], params, log=log, shards=shards)
+    bf16 = _with_params(r, r.params.replace(score_dtype="bfloat16"))
 
     # the program's answers first, then its state is freed for the references
     drawn, answers = {}, {}
@@ -101,13 +112,14 @@ def main(argv=None) -> None:
     for j, seed in enumerate(args.seeds):
         qs, pidx = drawn[seed]
         t0 = time.perf_counter()
-        plain_s, plain_p, reached = reference.plaid_topk(corpus, lists, qs[pidx], cfg["search"])
+        plain_s, plain_p, reached = reference.plaid_topk(
+            corpus, lists, qs[pidx], cfg["search"], shards=shards)
         row = {"workload": cell.name, "seed": seed, "plaid_sample": len(pidx),
                "probed_passages_max": int(reached.max()),
                "plain_seconds": time.perf_counter() - t0}
         if j < CONTROLS:
             answers[seed]["reference_bf16"] = reference.plaid_topk(
-                corpus, lists, qs[pidx], cfg["search"], low=True)[:2]
+                corpus, lists, qs[pidx], cfg["search"], shards=shards, low=True)[:2]
         for name, (s, p) in answers[seed].items():
             ref = reference.score_pids(corpus, qs[pidx], p)
             row[name] = {

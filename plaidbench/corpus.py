@@ -234,12 +234,18 @@ class Corpus:
         benchmark's own inverted lists (:func:`inverted_lists`)."""
         s = self.spec
         packed_fn = jax.jit(functools.partial(pack, nbits=s.nbits))
-        codes, packed, pairs_c, pairs_p = [], [], [], []
+        # written in place, block by block: the host holds the payload once
+        tok_off = np.zeros(s.passages + 1, np.int64)
+        np.cumsum(self.lens, out=tok_off[1:])
+        codes = np.empty(tok_off[-1], np.int32)
+        packed = np.empty((tok_off[-1], s.dim * s.nbits // 8), np.uint8)
+        pairs_c, pairs_p = [], []
         for i, n, pids in self.blocks(np.arange(s.passages, dtype=np.int32)):
             ids, buckets, valid = self.payload_block(pids)
             ids, valid = np.asarray(ids)[:n], np.asarray(valid)[:n]
-            codes.append(ids[valid])
-            packed.append(np.asarray(packed_fn(buckets))[:n][valid])
+            t0, t1 = tok_off[i], tok_off[i + n]
+            codes[t0:t1] = ids[valid]
+            packed[t0:t1] = np.asarray(packed_fn(buckets))[:n][valid]
             srt = np.sort(np.where(valid, ids, s.centroids), axis=1)
             first = np.ones_like(srt, bool)
             first[:, 1:] = srt[:, 1:] != srt[:, :-1]
@@ -250,7 +256,7 @@ class Corpus:
         pc, pp = np.concatenate(pairs_c), np.concatenate(pairs_p)
         order = np.argsort(pc, kind="stable")  # pids already ascend per code
         pairs = np.stack([pc[order].astype(np.int64), pp[order].astype(np.int64)], 1)
-        return np.concatenate(codes), np.concatenate(packed), pairs, inverted_lists(s, pc, pp, order)
+        return codes, packed, pairs, inverted_lists(s, pc, pp, order)
 
 
 def inverted_lists(spec: CorpusSpec, pc: np.ndarray, pp: np.ndarray, order: np.ndarray) -> dict:
@@ -273,17 +279,59 @@ def load_inverted_lists(corpus: Corpus) -> dict:
         return {k: z[k] for k in z.files}
 
 
-def load_or_build(corpus: Corpus, backend: str, params, log=print):
+def _layout(backend: str, n_shards: int | None) -> str:
+    """A saved index's layout: the program's code that reads it (backends
+    that share a format share it) and, for a sharded save, its shards."""
+    from repro.retrieval import registry
+
+    reader = registry.get_backend(backend).load.__func__.__qualname__
+    return reader + ("" if n_shards is None else f" x {n_shards} shards")
+
+
+def _saved_mismatch(path: pathlib.Path, spec: CorpusSpec, backend: str, shards: int) -> list[str]:
+    """How the index saved at ``path`` differs from what the configuration
+    asks for: each differing ``CorpusSpec`` field, and the layout."""
+    with open(path / "corpus.json") as f:
+        saved = json.load(f)
+    want = dataclasses.asdict(spec)
+    out = [
+        f"{k}: saved {saved.get(k)!r}, configuration {want.get(k)!r}"
+        for k in sorted(set(saved) | set(want)) if saved.get(k) != want.get(k)
+    ]
+    with open(path / "retriever.json") as f:
+        saved_backend = json.load(f)["backend"]
+    with open(path / "manifest.json") as f:
+        saved_shards = json.load(f).get("n_shards")
+    has = _layout(saved_backend, saved_shards)
+    need = _layout(backend, None if saved_shards is None else shards)
+    if has != need:
+        out.append(f"layout: saved {has} (backend {saved_backend}), "
+                   f"configuration {need} (backend {backend}, shards {shards})")
+    return out
+
+
+def load_or_build(corpus: Corpus, backend: str, params, log=print, *, shards: int = 1):
     """The program's retriever over this corpus: loaded with the program's
     own ``retrieval.load`` from the cache under ``plaidbench/``, or, on the
     first run in a checkout, assembled through ``core.index.assemble_index``
-    and saved there with the program's own save."""
+    and saved there with the program's own save (a sharded backend split
+    over the configuration's ``shards`` where it sets more than one).  A
+    saved index whose
+    corpus fields (``corpus.json``) or layout (``retriever.json``, and the
+    shard count of a sharded save against the configuration's ``shards``)
+    differ from the configuration's is refused, never loaded."""
     from repro import retrieval
     from repro.core.index import assemble_index
 
     s = corpus.spec
     path = CACHE_DIR / s.cache_key()
     if (path / "retriever.json").exists():
+        diff = _saved_mismatch(path, s, backend, shards)
+        if diff:
+            raise ValueError(
+                f"plaidbench: the index saved at {path} was built for another configuration "
+                f"({'; '.join(diff)}); delete it, or give the corpus another name"
+            )
         log(f"[index] load {path.name}")
         return retrieval.load(str(path), backend=backend, params=params), False
     log(f"[index] build {path.name}: {s.passages} passages, {corpus.num_tokens} tokens")
@@ -296,7 +344,8 @@ def load_or_build(corpus: Corpus, backend: str, params, log=print):
         pairs=pairs,
     )
     del codes, packed, pairs
-    r = retrieval.from_index(index, backend=backend, params=params)
+    split = {"n_shards": shards} if shards > 1 else {}  # else the program takes every device
+    r = retrieval.from_index(index, backend=backend, params=params, **split)
     tmp = path.with_name(f"{path.name}.tmp{os.getpid()}")
     shutil.rmtree(tmp, ignore_errors=True)
     os.makedirs(tmp.parent, exist_ok=True)

@@ -42,7 +42,8 @@ def main(argv=None) -> None:
     cfg, t = cell.config, cell.traffic
     params = _params(cfg)
     corpus = corpus_mod.Corpus(corpus_mod.CorpusSpec.from_config(cfg))
-    r, _ = corpus_mod.load_or_build(corpus, cfg["backend"], params, log=log)
+    r, _ = corpus_mod.load_or_build(
+        corpus, cfg["backend"], params, log=log, shards=spec_mod.shards(cfg))
     n = int(max(args.rates) * args.seconds) + 1
     qs, _ = corpus.queries(n, args.seed)
     _warm(r, qs, t, params)

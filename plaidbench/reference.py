@@ -18,6 +18,12 @@ and are scored in float32 at ``HIGHEST`` precision.
   ``max(ndocs / 4, k)``, then exact MaxSim and the top ``k``;
 * :func:`score_pids`: the exact MaxSim of given passages.
 
+Where a configuration splits its passages over ``shards`` chips
+(:func:`shard_ranges`), :func:`plaid_topk` runs stages 1 to 3 on each
+shard's pid range alone, with the cap clamped to the shard's size, and
+takes the top ``k`` of the union of every shard's finalists: each shard's
+own top ``k`` merged by score, as a corpus split over chips computes it.
+
 ``low=True`` (``control=True`` for the exhaustive pass) computes the same
 in bfloat16: the next precision below the configuration's, the control
 that the comparison in ``check`` has to refuse.
@@ -174,32 +180,79 @@ def _finalists(concepts, code_pids, code_off, pid_codes, pid_off, q, t_cs, *,
     return jnp.where(fin < n_pass, fin, -1), n_reached
 
 
-def plaid_topk(corpus: Corpus, lists: dict, qs: np.ndarray, search: dict, *, low=False):
+def shard_ranges(passages: int, shards: int) -> list[tuple[int, int]]:
+    """The pid range ``[lo, hi)`` of each of ``shards`` contiguous shards of
+    ``per = ceil(passages / shards)`` passages; the last may hold fewer."""
+    per = -(-passages // shards)
+    return [(min(i * per, passages), min((i + 1) * per, passages)) for i in range(shards)]
+
+
+def shard_cap(search: dict, passages: int, shards: int = 1) -> int:
+    """The candidate cap of one shard: ``candidate_cap`` clamped to the
+    shard's size, as the program clamps it."""
+    return min(search["candidate_cap"], max(-(-passages // shards), 2))
+
+
+def _shard_lists(lists: dict, ranges: list, per: int) -> list[dict]:
+    """The inverted lists of each shard's pids alone, in local pids
+    ``0 .. per`` (a shard past its last passage holds no codes), every
+    shard's arrays padded to one shape so that one program runs them all."""
+    cp, co = lists["code_pids"], lists["code_off"]
+    pc, po = lists["pid_codes"], lists["pid_off"]
+    parts = []
+    for lo, hi in ranges:
+        mine = (cp >= lo) & (cp < hi)  # a code's list ascends by pid: one slice of it
+        kept = np.zeros(len(cp) + 1, np.int64)
+        np.cumsum(mine, out=kept[1:])
+        pid_off = np.full(per + 1, po[hi] - po[lo], np.int64)
+        pid_off[: hi - lo + 1] = po[lo : hi + 1] - po[lo]
+        parts.append({
+            "pid_codes": pc[po[lo] : po[hi]], "pid_off": pid_off.astype(np.int32),
+            "code_pids": (cp[mine] - lo).astype(np.int32),
+            "code_off": kept[co].astype(np.int32),
+        })
+    for key in ("pid_codes", "code_pids"):
+        n = max(1, *(len(p[key]) for p in parts))
+        for p in parts:
+            p[key] = np.pad(p[key], (0, n - len(p[key])))
+    return parts
+
+
+def plaid_topk(corpus: Corpus, lists: dict, qs: np.ndarray, search: dict, *,
+               shards: int = 1, low=False):
     """PLAID's top-``k`` (scores, pids) of each query (nQ, nq, d) at the
     configuration's ``search`` settings, and each query's count of distinct
-    passages reached by its probes (above ``candidate_cap``, the cap cut)."""
+    passages reached by its probes (above the cap, the cap cut), the
+    largest over the shards where ``shards`` > 1 (:func:`shard_cap`)."""
     n_pass = corpus.spec.passages
-    cap = min(search["candidate_cap"], max(n_pass, 2))
+    ranges = shard_ranges(n_pass, shards)
+    cap = shard_cap(search, n_pass, shards)
     n2 = min(search["ndocs"], cap)
     n3 = min(max(search["ndocs"] // 4, search["k"]), n2)
-    dev = {k: jnp.asarray(v) for k, v in lists.items()}
+    parts = [lists] if shards == 1 else _shard_lists(lists, ranges, -(-n_pass // shards))
     static = dict(
         nprobe=search["nprobe"], cap=cap, n2=n2, n3=n3, low=low,
-        list_len=int(np.diff(lists["code_off"]).max()),
-        width=int(np.diff(lists["pid_off"]).max()),
+        list_len=max(int(np.diff(p["code_off"]).max()) for p in parts),
+        width=max(int(np.diff(p["pid_off"]).max()) for p in parts),
     )
     fin, reached = [], []
-    for q in qs:
-        f, r = _finalists(
-            corpus.concepts, dev["code_pids"], dev["code_off"], dev["pid_codes"],
-            dev["pid_off"], jnp.asarray(q), jnp.float32(search["t_cs"]), **static,
-        )
-        fin.append(f)
-        reached.append(r)
-    fin = np.asarray(jnp.stack(fin))
+    for (lo, _), part in zip(ranges, parts):
+        dev = {k: jnp.asarray(v) for k, v in part.items()}
+        f, r = [], []
+        for q in qs:
+            a, b = _finalists(
+                corpus.concepts, dev["code_pids"], dev["code_off"], dev["pid_codes"],
+                dev["pid_off"], jnp.asarray(q), jnp.float32(search["t_cs"]), **static,
+            )
+            f.append(a)
+            r.append(b)
+        f = np.asarray(jnp.stack(f))
+        fin.append(np.where(f >= 0, f + lo, -1))
+        reached.append(np.asarray(jnp.stack(r)))
+    fin = np.concatenate(fin, axis=1)
     exact = score_pids(corpus, qs, fin, low=low)
     exact = np.where(fin >= 0, exact, -np.inf)
     order = np.argsort(-exact, axis=1, kind="stable")[:, : search["k"]]
     top_s = np.take_along_axis(exact, order, axis=1)
     top_p = np.take_along_axis(fin, order, axis=1)
-    return top_s, np.where(np.isfinite(top_s), top_p, -1), np.asarray(jnp.stack(reached))
+    return top_s, np.where(np.isfinite(top_s), top_p, -1), np.max(reached, axis=0)

@@ -160,12 +160,27 @@ def _window(cell, r, qs, seconds: float, seed: int, tracer):
     return win, {"batches": win.n // t["batch"], "batch": t["batch"]}
 
 
-def _free(r) -> None:
-    """Delete the program's device state before the reference runs."""
+def _device_arrays(obj, seen: set):
+    """Every ``jax.Array`` that ``obj`` holds: the leaves of each of its
+    attributes as a pytree, and those of the program's own objects inside
+    them (an engine, an index that is not a pytree), each object once."""
     import jax
 
-    for leaf in jax.tree.leaves(getattr(r, "index", None)):
-        if isinstance(leaf, jax.Array):
+    if id(obj) in seen or not hasattr(obj, "__dict__"):
+        return
+    seen.add(id(obj))
+    for value in vars(obj).values():
+        for leaf in jax.tree.leaves(value):
+            if isinstance(leaf, jax.Array):
+                yield leaf
+            elif type(leaf).__module__.startswith("repro."):
+                yield from _device_arrays(leaf, seen)
+
+
+def _free(r) -> None:
+    """Delete the program's device state before the reference runs."""
+    for leaf in list(_device_arrays(r, set())):
+        if not leaf.is_deleted():
             leaf.delete()
     gc.collect()
 
@@ -190,6 +205,12 @@ def run(argv=None) -> dict:
 
     cell = spec_mod.load_cell(ROOT / "BENCHMARK.json", args.workload)
     root = cell.root
+    shards = spec_mod.shards(cell.config)
+    if shards > 1 and cell.workload["chips"] != shards:
+        raise SystemExit(
+            f"plaidbench: configuration {cell.config['name']!r} splits its passages over "
+            f"{shards} chips (shards) and cell {cell.name!r} asks for {cell.workload['chips']}"
+        )
 
     from repro import compile_cache
 
@@ -212,7 +233,7 @@ def run(argv=None) -> dict:
     cfg, t = cell.config, cell.traffic
     params = _params(cfg)
     corpus = corpus_mod.Corpus(corpus_mod.CorpusSpec.from_config(cfg))
-    r, built = corpus_mod.load_or_build(corpus, cfg["backend"], params, log=log)
+    r, built = corpus_mod.load_or_build(corpus, cfg["backend"], params, log=log, shards=shards)
     if t["kind"] == "open_loop":
         from plaidbench.traffic import poisson_schedule
 
@@ -269,13 +290,15 @@ def run(argv=None) -> dict:
     pidx = check.sample(answered, t["plaid_sample"], args.seed, stream=4)
     _, plain_p, reached = reference.plaid_topk(
         corpus, corpus_mod.load_inverted_lists(corpus), qs[qrow[pidx]], cfg["search"],
+        shards=shards,
     )
     values["plaid_miss"] = check.plaid_miss(np.stack([win.pids[i] for i in pidx]), plain_p)
     ok, checks = check.verdict(values, cfg["limits"])
     recall = check.recall(served_p, exact_p, k)
     log(f"[reference] sample={len(idx)} plaid_sample={len(pidx)} "
         f"seconds={time.perf_counter() - t_ref:.3f} exhaustive_s={exhaustive_s:.3f} recall_k={recall:.6f} "
-        f"probed_passages_max={int(reached.max())} cap={cfg['search']['candidate_cap']}")
+        f"probed_passages_max={int(reached.max())} shards={shards} "
+        f"cap={reference.shard_cap(cfg['search'], corpus.spec.passages, shards)}")
 
     # ---- metrics -----------------------------------------------------------
     device = {

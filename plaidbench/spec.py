@@ -1,6 +1,14 @@
 """Find a cell's pieces by name: BENCHMARK.json, then one file per piece.
 
-* a configuration: the ``file`` its ``configs`` entry names;
+* a configuration: the ``file`` its ``configs`` entry names: the corpus
+  (``corpus.CorpusSpec``'s keys), ``backend``, ``matmul_precision``,
+  ``search``, ``limits`` and the optional ``shards`` (1 when absent): the
+  passages are split over ``shards`` chips in contiguous pid ranges of
+  ``per = ceil(passages / shards)``, shard ``i`` owning
+  ``[i * per, min((i + 1) * per, passages))`` as ``plaid-sharded`` splits
+  them, and ``correct`` judges PLAID shard by shard
+  (``reference.plaid_topk``); a cell of such a configuration asks for
+  ``shards`` chips;
 * a traffic mix: ``plaidbench/traffic/<traffic>.json``;
 * a per-layer metric's reader: ``plaidbench/metrics/<name>.py``, whose
   ``read(ctx)`` returns a number, or None where it finds nothing to read;
@@ -49,6 +57,14 @@ class Cell:
 
     def _has(self, metric: dict) -> bool:
         return "workloads" not in metric or self.name in metric["workloads"]
+
+
+def shards(config: dict) -> int:
+    """The configuration's ``shards``: a whole number, 1 when absent."""
+    n = config.get("shards", 1)
+    if isinstance(n, bool) or not isinstance(n, int) or n < 1:
+        raise ValueError(f"configuration {config.get('name')!r}: shards must be a whole number >= 1, not {n!r}")
+    return n
 
 
 def _load_json(path: pathlib.Path) -> dict:

@@ -28,10 +28,10 @@ search = backends.PlaidRetriever.search_batch
 cut = {{}}
 
 def broken(self, qs, q_masks=None, **kw):
-    if fault == "cap":  # stage 1's candidate set cut to a quarter of its cap
-        if id(self) not in cut:
+    if fault == "cap":  # stage 1's candidate set cut to a sixteenth of its cap, below every
+        if id(self) not in cut:  # query's probed set, so that each sampled answer can miss
             p = self.params
-            cut[id(self)] = type(self)(self.index, p.replace(candidate_cap=p.candidate_cap // 4))
+            cut[id(self)] = type(self)(self.index, p.replace(candidate_cap=p.candidate_cap // 16))
         return search(cut[id(self)], qs, q_masks, **kw)
     res = search(self, qs, q_masks, **kw)
     s, p = np.array(res.scores), np.array(res.pids)
